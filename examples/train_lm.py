@@ -12,8 +12,8 @@ import tempfile
 
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
-from repro.core.cluster import cpu_host_config
 from repro.launch.mesh import make_host_mesh
+from repro.launch.train import mesh_cluster
 from repro.optim import adamw
 from repro.runtime.train_loop import Trainer, TrainerConfig
 
@@ -30,8 +30,7 @@ def main():
     shape = ShapeConfig("cpu_train", seq_len=64, global_batch=16,
                         mode="train")
     mesh = make_host_mesh()
-    cc = cpu_host_config().with_mesh(tuple(mesh.devices.shape),
-                                     tuple(mesh.axis_names))
+    cc = mesh_cluster(mesh)
     ckpt = args.ckpt_dir or tempfile.mkdtemp(prefix="repro_ckpt_")
     tcfg = TrainerConfig(steps=args.steps, log_every=20,
                          checkpoint_every=100, ckpt_dir=ckpt)

@@ -86,8 +86,8 @@ def _canon_dtype(dtype) -> str:
     return s
 
 
-# TPU v5e — the assignment's target numbers: 197 TFLOP/s bf16, 819 GB/s HBM,
-# ~50 GB/s per ICI link.
+# TPU v5e — Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+# 394 TOP/s int8, 16 GB of HBM at 819 GB/s; ~50 GB/s per ICI link.
 TPU_V5E = ChipSpec(
     name="tpu_v5e",
     peak_flops={
@@ -107,7 +107,9 @@ TPU_V5E = ChipSpec(
     cost_per_chip_hour=1.20,
 )
 
-# TPU v5p — the training-class sibling: ~2.3x the bf16 rate, ~6x the HBM,
+# TPU v5p — Google Cloud documentation, "TPU v5p": 459 TFLOP/s bf16,
+# 918 TOP/s int8, 95 GB of HBM at 2,765 GB/s.
+# The training-class sibling: ~2.3x the bf16 rate, ~6x the HBM,
 # bigger ICI domain, at a materially higher price point.  The interesting
 # resource decisions (is a smaller count of fat chips cheaper than a pod of
 # thin ones?) need exactly this contrast in the table.
@@ -131,7 +133,9 @@ TPU_V5P = ChipSpec(
     cost_per_chip_hour=4.20,
 )
 
-# TPU v6e (Trillium) — ~4.7x the v5e bf16 rate and 2x its HBM bandwidth at
+# TPU v6e (Trillium) — Google Cloud documentation, "TPU v6e": 918 TFLOP/s
+# bf16, 1,836 TOP/s int8, 32 GB of HBM at 1,640 GB/s.
+# ~4.7x the v5e bf16 rate and 2x its HBM bandwidth at
 # ~2.2x the price: usually the fastest *and* the cheapest per step, unless
 # the workload is HBM-capacity bound (32 GB/chip).
 TPU_V6E = ChipSpec(
@@ -153,9 +157,9 @@ TPU_V6E = ChipSpec(
     cost_per_chip_hour=2.70,
 )
 
-# A CPU "chip" used ONLY by the accuracy benchmark (paper §3.4): the cost
-# model's fidelity is validated against wall time on the machine we actually
-# have.  Single core (the container), DGEMM-ish peak, DRAM bandwidth.
+# A CPU "chip": the host backend JAX falls back to, used by the CPU
+# rehearsal runs and the accuracy benchmark (paper §3.4).  Single core,
+# DGEMM-ish peak, DRAM bandwidth — hand-set, not published.
 CPU_HOST = ChipSpec(
     name="cpu_host",
     peak_flops={
@@ -180,6 +184,27 @@ CHIPS: Dict[str, ChipSpec] = {
     "tpu_v5p": TPU_V5P,
     "tpu_v6e": TPU_V6E,
 }
+
+# The same chips keyed by the ``device_kind`` JAX reports for them.  A kind
+# that is not listed is an error, never a default: a plan costed for the
+# wrong chip is silently wrong.
+DEVICE_KINDS: Dict[str, ChipSpec] = {
+    "TPU v5 lite": TPU_V5E,
+    "TPU v5": TPU_V5P,
+    "TPU v6 lite": TPU_V6E,
+    "cpu": CPU_HOST,
+}
+
+
+def chip_for_device_kind(kind: str) -> ChipSpec:
+    """The :class:`ChipSpec` of a JAX ``device_kind``; raises on a kind the
+    table does not know."""
+    try:
+        return DEVICE_KINDS[kind]
+    except KeyError:
+        raise ValueError(
+            f"unknown device kind {kind!r}: add its ChipSpec to "
+            f"DEVICE_KINDS (known: {sorted(DEVICE_KINDS)})") from None
 
 
 # ---------------------------------------------------------------------------
@@ -508,7 +533,7 @@ def single_chip_config(**kw) -> ClusterConfig:
 
 
 def cpu_host_config(**kw) -> ClusterConfig:
-    """For the paper-§3.4 accuracy benchmark on this container."""
+    """One CPU host device (the paper-§3.4 accuracy benchmark)."""
     return ClusterConfig(
         chip=CPU_HOST,
         mesh_shape=(1,),
@@ -517,6 +542,15 @@ def cpu_host_config(**kw) -> ClusterConfig:
         matmul_util=0.60,
         **kw,
     )
+
+
+def local_cluster_config(device_kind: str, mesh_shape: Tuple[int, ...],
+                         mesh_axes: Tuple[str, ...]) -> ClusterConfig:
+    """The cluster a process sees: its chip by ``device_kind`` and its local
+    device mesh.  A CPU host keeps :func:`cpu_host_config`'s constants."""
+    chip = chip_for_device_kind(device_kind)
+    base = cpu_host_config() if chip is CPU_HOST else ClusterConfig(chip=chip)
+    return base.with_mesh(mesh_shape, mesh_axes)
 
 
 DTYPE_BYTES = {

@@ -49,6 +49,14 @@ from repro.core.symbols import MemState, TensorStat
 # by the same constant, so a drift here cannot silently unsound the floors.
 OVERLAP_FRACTION = 0.7
 
+# Attention shapes the train-step HBM terms follow (repro.models.layers.
+# attention): sequences up to DENSE_ATTN_MAX_SEQ attend densely, longer
+# ones run the rematerialized chunked path over ATTN_KV_CHUNK-key blocks;
+# the dense backward keeps ATTN_SAVED_ROWS fp32 score tensors per layer.
+DENSE_ATTN_MAX_SEQ = 2048
+ATTN_KV_CHUNK = 1024
+ATTN_SAVED_ROWS = 3
+
 # The enumerated microbatch knob (train mode).  For pipelined roles the
 # knob is reinterpreted as the schedule's M; its ceiling bounds how far a
 # pipeline can amortize its (S-1) fill/drain bubbles, which is what the
@@ -656,14 +664,21 @@ def resident_components(arch: ArchConfig, shape: ShapeConfig,
     wsh = max(tp * fsdp * (ep if arch.moe else 1), 1)
     comp: Dict[str, float] = {"params": pc["total"] * bpe / (wsh * pp)}
     if shape.mode == "train":
-        # adam m,v (fp32) + fp32 transients during the update, sharded like
-        # params (+dp if fsdp); calibrated against compiled memory_analysis
+        # The train terms below are calibrated against
+        # compiled.memory_analysis() of the qwen1.5-0.5b train step on one
+        # v5e (1-8 x 2048/4096 tokens, every remat, 1-4 microbatches):
+        # the sum stays >= argument + temp bytes and within ~1.4x of them.
+        # adam m,v (fp32), sharded like params (+dp if fsdp or zero1); the
+        # update's fp32 transients are per-leaf and never all live at once
         opt_shards = wsh * (dp if (fsdp > 1 or plan.zero1) else 1)
-        comp["opt_state"] = 4 * pc["total"] * 4 / (pmax(opt_shards, wsh) * pp)
-        # gradients: resident fp32 accumulator regardless of microbatching
-        # (grad_reduce_dtype only changes the wire payload, not the buffer;
-        # calibrated against compiled memory_analysis)
-        comp["grads"] = pc["total"] * 4 / (wsh * pp)
+        comp["opt_state"] = 2 * pc["total"] * 4 / (pmax(opt_shards, wsh) * pp)
+        # gradients: fp32-sized buffers; a microbatched step adds its fp32
+        # accumulator and the current microbatch's gradient beside it
+        # (grad_reduce_dtype only changes the wire payload, not the buffers)
+        micro = _kv(plan.microbatches)
+        grad_bytes = (np.where(micro > 1, 4 + 4 + bpe, 4) if is_vec(micro)
+                      else 4 + 4 + bpe if micro > 1 else 4)
+        comp["grads"] = pc["total"] * grad_bytes / (wsh * pp)
         # activations saved for backward, per token per layer:
         #   replicated residual-stream parts (~d) + head/ff-sharded parts
         d = arch.d_model
@@ -675,10 +690,27 @@ def resident_components(arch: ArchConfig, shape: ShapeConfig,
             ff_eff = arch.ssm.expand * d
         else:
             ff_eff = arch.d_ff
-        fac = {"none": (5.0, 3.0), "selective": (2.0, 1.0),
-               "full": (2.0, 0.0)}[plan.remat]
+        fac = {"none": (5.0, 13.0), "selective": (1.0, 4.0),
+               "full": (1.0, 0.0)}[plan.remat]
         per_tok = (fac[0] * d * bpe
                    + fac[1] * (hd_total + ff_eff) * bpe / max(tp, 1))
+        # attention score rows: fp32 [heads, kv] per token.  Up to
+        # DENSE_ATTN_MAX_SEQ the model attends densely, and without remat
+        # every attention layer saves ATTN_SAVED_ROWS of them for backward;
+        # the layer under backward holds one more (ATTN_SAVED_ROWS + 1),
+        # over the whole sequence when dense, over one kv chunk when the
+        # rematerialized chunked path runs
+        if arch.family == "ssm":
+            n_attn = 0
+        elif arch.family == "hybrid":
+            n_attn = arch.n_layers // arch.hybrid.attn_every
+        else:
+            n_attn = arch.n_layers
+        dense_attn = shape.seq_len <= DENSE_ATTN_MAX_SEQ
+        att_row = (max(arch.n_heads, 1) / max(tp, 1) * 4
+                   * (shape.seq_len if dense_attn else ATTN_KV_CHUNK))
+        if dense_attn and plan.remat == "none" and n_attn:
+            per_tok += ATTN_SAVED_ROWS * att_row * n_attn / arch.n_layers
         tokens_dev = shape.tokens / pmax(dp * sp * _kv(plan.microbatches), 1)
         if pp > 1:
             # 1F1B-style schedule memory: a stage stashes activations for
@@ -691,8 +723,11 @@ def resident_components(arch: ArchConfig, shape: ShapeConfig,
                                  * pmin(_kv(plan.microbatches), pp))
         else:
             comp["act_stash"] = tokens_dev * arch.n_layers * per_tok
-        # chunked-CE head: [ce_chunk, vocab] fp32 (+bwd copy), tp-sharded
-        comp["ce_head"] = 2 * 2048 * arch.vocab_size * 4 / max(tp, 1)
+        if n_attn:
+            comp["attn_backward"] = (tokens_dev * (ATTN_SAVED_ROWS + 1)
+                                     * att_row)
+        # chunked-CE head: one [ce_chunk, vocab] fp32 logits block, tp-sharded
+        comp["ce_head"] = 2048 * arch.vocab_size * 4 / max(tp, 1)
     else:
         tokens_dev = shape.tokens / max(dp * sp, 1)
         if shape.mode == "decode":
@@ -1295,10 +1330,10 @@ def _beam_search(arch: ArchConfig, shape: ShapeConfig, cc: ClusterConfig,
     grads, micro=1 — except pipelined roles, whose representative runs at
     the largest valid M: a pipeline at M=1 is all bubble and would be
     unfairly dropped from the beam).  A role whose *most frugal*
-    completion (remat=full, max microbatches) still exceeds the HBM budget
-    is an infeasible prefix and is dropped without expanding it — unless
-    nothing fits, in which case all roles stay so the caller sees the
-    honest OOM ranking.
+    completion (remat=full at its leanest microbatch count) still exceeds
+    the HBM budget is an infeasible prefix and is dropped without
+    expanding it — unless nothing fits, in which case all roles stay so
+    the caller sees the honest OOM ranking.
 
     Stage 2 — remat x microbatch per surviving role.  For a fixed (role,
     micro) the cost model makes recompute strictly slower and strictly
@@ -1328,10 +1363,14 @@ def _beam_search(arch: ArchConfig, shape: ShapeConfig, cc: ClusterConfig,
                                        gdtypes[0]),
                             cc, cache, stats)
         stage1.append((role, d))
-        frugal_micro = max((m for m in micro_opts
-                            if _micro_valid(role, shape, cc, m)), default=1)
-        frugal = _role_plan(role, cc, remats[-1], frugal_micro, gdtypes[0])
-        if estimate_hbm(arch, shape, frugal, cc) <= budget:
+        # the frugal remat is the heaviest; the frugal microbatch count is
+        # searched (more microbatches shrink activations but add the
+        # gradient accumulator)
+        frugal_hbm = min(
+            estimate_hbm(arch, shape,
+                         _role_plan(role, cc, remats[-1], m, gdtypes[0]), cc)
+            for m in micro_opts if _micro_valid(role, shape, cc, m))
+        if frugal_hbm <= budget:
             kept.append((role, d))
         else:
             stats.pruned_infeasible += 1

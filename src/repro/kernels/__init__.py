@@ -7,7 +7,10 @@
   * matmul_epilogue — blocked matmul with fused bias/silu/gelu/layernorm
                       epilogue + cast sinking (the fusion="full" variants)
 
-Each has a jit'd wrapper in ops.py and a pure-jnp oracle in ref.py;
-validated in interpret mode on CPU, targeted at TPU via BlockSpec tiling.
+Each has a jit'd wrapper in ops.py and a pure-jnp oracle in ref.py; the
+wrappers compile for a TPU backend and interpret elsewhere.  Validated in
+interpret mode on CPU (tests/test_kernels.py), compiled for a described v5e
+(tests/test_tpu_compile.py), and checked against ref.py on the chip
+(chip_smoke.py).
 """
 from repro.kernels import ops, ref

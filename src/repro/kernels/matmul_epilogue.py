@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 EPILOGUES = (None, "bias", "silu", "gelu", "layernorm")
 _LN_EPS = 1e-6
 
@@ -83,7 +79,7 @@ def matmul_epilogue(x: jax.Array, w: jax.Array,
                     epilogue: Optional[str] = None,
                     out_dtype: Optional[jnp.dtype] = None,
                     bm: int = 256, bn: int = 256, bk: int = 256,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """``epilogue(x @ w)`` written once, in ``out_dtype``.
 
     x: [m, k]; w: [k, n]; bias: [n] (required iff epilogue == "bias").
@@ -120,7 +116,7 @@ def matmul_epilogue(x: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )

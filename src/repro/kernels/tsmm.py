@@ -29,10 +29,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# pallas renamed TPUCompilerParams -> CompilerParams; accept either
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
 
 def _upper_pairs(nb: int) -> Tuple[np.ndarray, np.ndarray]:
     """Linearized upper-triangular block pairs (i <= j)."""
@@ -74,7 +70,7 @@ def _tsmm_kernel(i_ref, j_ref, xi_ref, xj_ref, out_ref, acc_ref, *,
 @functools.partial(jax.jit,
                    static_argnames=("bm", "bn", "reg", "interpret"))
 def tsmm_upper(x: jax.Array, *, bm: int = 512, bn: int = 256,
-               reg: float = 0.0, interpret: bool = True) -> jax.Array:
+               reg: float = 0.0, interpret: bool) -> jax.Array:
     """Upper-triangular blocks of X^T X + reg*I (lower-left tiles zero).
 
     x: [m, n] with m % bm == 0 and n % bn == 0.  ``reg`` is the ridge
@@ -102,7 +98,7 @@ def tsmm_upper(x: jax.Array, *, bm: int = 512, bn: int = 256,
         functools.partial(_tsmm_kernel, k_steps=kk, reg=reg),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, n), x.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )

@@ -390,14 +390,13 @@ def component_costs(arch: ArchConfig, shape: ShapeConfig, plan: ShardingPlan,
 
         dp_axes = tuple(a for a in plan.batch_axes if a in mesh.shape)
         if _sz(mesh, dp_axes) > 1 and not plan.fsdp_axes:
-            from jax.experimental import shard_map as shmap
             gd = jnp.dtype(plan.grad_reduce_dtype)
 
             def psum_fn(g):
                 return jax.tree.map(lambda x: jax.lax.psum(x, dp_axes), g)
             in_specs = jax.tree.map(lambda s: s.spec, psh)
-            fn = shmap.shard_map(psum_fn, mesh=mesh, in_specs=(in_specs,),
-                                 out_specs=in_specs)
+            fn = jax.shard_map(psum_fn, mesh=mesh, in_specs=(in_specs,),
+                               out_specs=in_specs)
             gspecs = jax.tree.map(
                 lambda sds, sh: _sds(sds.shape, gd, sh), pshapes, psh)
             comps.append(Component("grad_reduce", 1,

@@ -1,15 +1,17 @@
-"""Production mesh construction.
+"""Device meshes.
 
-``make_production_mesh`` is a FUNCTION (importing this module never touches
-jax device state).  Shapes per the assignment: one v5e pod = (16, 16) over
-("data", "model"); two pods = (2, 16, 16) over ("pod", "data", "model"),
-the "pod" axis crossing DCN.
+Every helper is a FUNCTION (importing this module never touches jax device
+state).  ``make_production_mesh`` builds the dry-run shapes: one v5e pod =
+(16, 16) over ("data", "model"); two pods = (2, 16, 16) over ("pod",
+"data", "model"), the "pod" axis crossing DCN.  ``make_host_mesh`` builds
+the mesh of the devices this process sees, which is what a real run uses.
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,29 +20,26 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    # jax >= 0.5 takes axis_types (and defaults axes to Auto); 0.4.x has
-    # neither the kwarg nor jax.sharding.AxisType — same semantics either way
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def abstract_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Version-portable jax.sharding.AbstractMesh (device-free mesh for
-    sharding-rule tests): jax 0.4.x wants ((name, size), ...) pairs, newer
-    jax wants (sizes, names)."""
-    from jax.sharding import AbstractMesh
-
-    try:
-        return AbstractMesh(tuple(shape), tuple(axes))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    """Device-free mesh for sharding-rule tests."""
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
-def make_host_mesh():
-    """Whatever devices exist locally, as a 1-axis 'data' mesh (tests)."""
-    n = len(jax.devices())
-    return make_mesh((n,), ("data",))
+def host_mesh_shape(n_devices: int) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:
+    """(shape, axes) of the local mesh over ``n_devices``: four chips form a
+    2x2 ("data", "model") mesh, so the planner can give the model axis a
+    role; any other count is one "data" axis."""
+    if n_devices == 4:
+        return (2, 2), ("data", "model")
+    return (n_devices,), ("data",)
+
+
+def make_host_mesh(devices=None):
+    """The mesh over ``devices`` (default: every device this process sees)."""
+    devices = list(jax.devices() if devices is None else devices)
+    return make_mesh(*host_mesh_shape(len(devices)), devices=devices)

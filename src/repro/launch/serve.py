@@ -12,6 +12,7 @@ import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.runtime.serve_engine import Request, ServeEngine
 
@@ -27,6 +28,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
+    print(f"compile cache: {enable_compile_cache()}")
     arch = get_config(args.arch)
     if args.reduced:
         arch = dataclasses.replace(arch.reduced(), dtype="float32")

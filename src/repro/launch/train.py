@@ -1,34 +1,93 @@
 """Training driver: ``python -m repro.launch.train --arch <id> [...]``.
 
-Cost-model-driven by default (--autoplan): the planner enumerates sharding
-plans for the requested mesh, ranks them with C(P, cc), and the winner
-configures the jitted step — the paper's optimizer in the driver's seat.
+Cost-model-driven: the planner enumerates sharding plans for the mesh,
+ranks them with C(P, cc), and the winner configures the jitted step — the
+paper's optimizer in the driver's seat.
 
-On this CPU container use --reduced --mesh host for a real run; the
-production meshes are exercised via dryrun.py.
+``--mesh host`` (the default) runs on the devices this process sees and
+prices the plans for the chip JAX reports there (``device_kind``): a TPU
+v5e host is costed as ``tpu_v5e``, the CPU backend as ``cpu_host``, and an
+unknown chip is an error.  One device is a (1,) "data" mesh, four are a
+2x2 ("data", "model") mesh.  A winner whose HBM estimate exceeds the
+chip's budget is refused before anything compiles.  On the CPU use
+``--reduced`` for a tiny same-family model.  ``--mesh single|multi`` are
+the 256- and 512-chip pod meshes (see dryrun.py for compile-only runs).
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
 import json
-
-import jax
+import sys
+from typing import List, Optional, Tuple
 
 from repro.configs import ARCH_IDS, SHAPES, get_config
-from repro.configs.base import ShapeConfig
-from repro.core.cluster import (ClusterConfig, cpu_host_config,
-                                multi_pod_config, single_pod_config)
-from repro.core.planner import choose_plan
+from repro.configs.base import ArchConfig, ShapeConfig
 from repro.core import explain as explain_mod
-from repro.core.planner import build_step_program
+from repro.core.cluster import (ClusterConfig, local_cluster_config,
+                                multi_pod_config, single_pod_config)
 from repro.core.costmodel import estimate
+from repro.core.planner import PlanDecision, build_step_program, choose_plan
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.optim import adamw
 from repro.runtime.train_loop import Trainer, TrainerConfig
 
 
-def main() -> None:
+class InfeasiblePlan(RuntimeError):
+    """The planner's best plan does not fit the chip's HBM budget."""
+
+
+def mesh_cluster(mesh) -> ClusterConfig:
+    """The :class:`ClusterConfig` of a local device mesh: its chip by
+    ``device_kind`` and its shape and axis names."""
+    kind = mesh.devices.flat[0].device_kind
+    return local_cluster_config(kind, tuple(mesh.devices.shape),
+                                tuple(mesh.axis_names))
+
+
+def rank_plans(arch: ArchConfig, shape: ShapeConfig, cc: ClusterConfig,
+               top_k: int = 3) -> List[PlanDecision]:
+    """The planner's top plans; raises :class:`InfeasiblePlan` when even
+    the best one does not fit ``cc``'s HBM budget."""
+    decisions = choose_plan(arch, shape, cc, top_k=top_k)
+    best = decisions[0]
+    if not best.feasible:
+        raise InfeasiblePlan(
+            f"best plan {best.plan.describe()} needs estimate_hbm "
+            f"{best.hbm_est / 1e9:.2f} GB per device, over the "
+            f"{cc.hbm_budget / 1e9:.2f} GB budget of {cc.chip.name} "
+            f"(mesh {cc.mesh_shape}); shrink --global-batch or --seq-len")
+    return decisions
+
+
+def make_trainer(arch: ArchConfig, shape: ShapeConfig, mesh, *,
+                 steps: int, lr: float = 3e-4, ckpt_dir: Optional[str] = None,
+                 compress: str = "none", log_every: Optional[int] = None,
+                 cc: Optional[ClusterConfig] = None
+                 ) -> Tuple[Trainer, List[PlanDecision]]:
+    """Cost the plans for ``mesh`` (or ``cc``), refuse an infeasible winner,
+    and build the :class:`Trainer` that runs it."""
+    cc = cc if cc is not None else mesh_cluster(mesh)
+    decisions = rank_plans(arch, shape, cc)
+    tcfg = TrainerConfig(steps=steps, ckpt_dir=ckpt_dir,
+                         compress_scheme=compress,
+                         log_every=log_every or max(steps // 10, 1))
+    opt = adamw.AdamWConfig(lr=lr, total_steps=steps)
+    trainer = Trainer(arch, shape, cc, mesh, plan=decisions[0].plan,
+                      opt_cfg=opt, tcfg=tcfg)
+    return trainer, decisions
+
+
+def print_ranking(decisions: List[PlanDecision], cc: ClusterConfig) -> None:
+    print(f"== cost-based plan ranking ({cc.chip.name}, mesh "
+          f"{cc.mesh_shape} {cc.mesh_axes}) ==")
+    for d in decisions:
+        print(f"  {d.plan.describe():60s} T={d.time*1e3:9.2f}ms "
+              f"hbm={d.hbm_est/1e9:6.2f}GB feasible={d.feasible}")
+
+
+def main(argv: Optional[List[str]] = None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
     ap.add_argument("--shape", default="train_4k", choices=list(SHAPES))
@@ -45,12 +104,11 @@ def main() -> None:
                     choices=["none", "bf16", "int8_ef"])
     ap.add_argument("--explain", action="store_true",
                     help="print the costed analytical plan and exit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     arch = get_config(args.arch)
     if args.reduced:
-        arch = arch.reduced()
-        arch = dataclasses.replace(arch, dtype="float32")
+        arch = dataclasses.replace(arch.reduced(), dtype="float32")
     shape = SHAPES[args.shape]
     if args.global_batch or args.seq_len:
         shape = dataclasses.replace(
@@ -59,29 +117,27 @@ def main() -> None:
 
     if args.mesh == "host":
         mesh = make_host_mesh()
-        cc = cpu_host_config().with_mesh(
-            tuple(mesh.devices.shape), tuple(mesh.axis_names))
+        cc = mesh_cluster(mesh)
     else:
         mesh = make_production_mesh(multi_pod=args.mesh == "multi")
         cc = multi_pod_config() if args.mesh == "multi" else single_pod_config()
 
-    decisions = choose_plan(arch, shape, cc, top_k=3)
-    print("== cost-based plan ranking ==")
-    for d in decisions:
-        print(f"  {d.plan.describe():60s} T={d.time*1e3:9.2f}ms "
-              f"hbm={d.hbm_est/1e9:6.2f}GB feasible={d.feasible}")
-    best = decisions[0]
     if args.explain:
-        prog = build_step_program(arch, shape, best.plan, cc)
+        decisions = choose_plan(arch, shape, cc, top_k=3)
+        print_ranking(decisions, cc)
+        prog = build_step_program(arch, shape, decisions[0].plan, cc)
         print(explain_mod.explain(estimate(prog, cc), max_depth=3))
         return
 
-    tcfg = TrainerConfig(steps=args.steps, ckpt_dir=args.ckpt_dir,
-                         compress_scheme=args.compress,
-                         log_every=max(args.steps // 10, 1))
-    opt = adamw.AdamWConfig(lr=args.lr, total_steps=args.steps)
-    trainer = Trainer(arch, shape, cc, mesh, plan=best.plan, opt_cfg=opt,
-                      tcfg=tcfg)
+    print(f"compile cache: {enable_compile_cache()}")
+    try:
+        trainer, decisions = make_trainer(
+            arch, shape, mesh, steps=args.steps, lr=args.lr,
+            ckpt_dir=args.ckpt_dir, compress=args.compress, cc=cc)
+    except InfeasiblePlan as e:
+        print(f"refusing to train: {e}", file=sys.stderr)
+        raise SystemExit(2) from None
+    print_ranking(decisions, cc)
     result = trainer.run(on_metrics=lambda m: print(json.dumps(m)))
     hist = result["history"]
     if hist:

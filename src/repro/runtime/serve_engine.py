@@ -167,8 +167,8 @@ class ServeEngine:
         logits, self._cache = self._prefill(self.params,
                                             jnp.asarray(prompts), cache,
                                             frontend)
-        dt = time.perf_counter() - t0
         tok = np.asarray(self._sample(logits))
+        dt = time.perf_counter() - t0
         new_rids = {s.rid for s in admitted}
         for i, s in enumerate(batch):
             s.pending = int(tok[i])

@@ -25,6 +25,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.checkpoint import store
 from repro.configs.base import ArchConfig, ShapeConfig
@@ -286,8 +287,18 @@ class Trainer:
         step_fn = make_train_step(self.model, self.opt_cfg, plan,
                                   compress_scheme=self.tcfg.compress_scheme,
                                   use_kernel=self.tcfg.use_kernel)
+        # the error-feedback state: per-parameter residuals for int8_ef,
+        # otherwise one replicated placeholder scalar per parameter
+        rep = NamedSharding(mesh, PartitionSpec())
+        self.ef_sh = compress.EFState(
+            residual=self.param_sh if self.tcfg.compress_scheme == "int8_ef"
+            else jax.tree.map(lambda _: rep, self.param_sh))
         donate = (0, 1) if self.tcfg.donate else ()
-        self.train_step = jax.jit(step_fn, donate_argnums=donate)
+        # outputs keep the input shardings, so step N+1 reuses step N's
+        # executable instead of recompiling for GSPMD's choice of layout
+        self.train_step = jax.jit(
+            step_fn, donate_argnums=donate,
+            out_shardings=(self.param_sh, self.opt_sh, self.ef_sh, rep))
         self.monitor = StepTimeMonitor()
         self.recalibrator = (OnlineRecalibrator(arch, shape, cc,
                                                 plan=self.plan)
@@ -303,10 +314,13 @@ class Trainer:
                              out_shardings=self.param_sh)(rng)
             opt_state = jax.jit(partial(adamw.init, self.opt_cfg),
                                 out_shardings=self.opt_sh)(params)
-        ef = compress.init_error_feedback(params) \
-            if self.tcfg.compress_scheme == "int8_ef" else \
-            compress.EFState(residual=jax.tree.map(lambda p: jnp.zeros((),
-                             jnp.float32), params))
+            if self.tcfg.compress_scheme == "int8_ef":
+                init_ef = compress.init_error_feedback
+            else:
+                def init_ef(ps):
+                    return compress.EFState(residual=jax.tree.map(
+                        lambda p: jnp.zeros((), jnp.float32), ps))
+            ef = jax.jit(init_ef, out_shardings=self.ef_sh)(params)
         return params, opt_state, ef
 
     def maybe_resume(self, params, opt_state):

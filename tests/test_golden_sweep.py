@@ -29,32 +29,35 @@ _spec.loader.exec_module(_regen)
 # axis existed.  Any drift here means the flat model was disturbed — which
 # is a bug, not a regeneration event (the new-axis cells live in
 # sweep_golden.json and MAY move with intentional cost-model changes; these
-# may not).
+# may not).  The only sanctioned move is a re-anchoring against the
+# compiler or the chip: the train cells here moved once, when the HBM
+# pre-filter's terms were reconciled with the compiled v5e train step's
+# memory analysis and some winners changed.
 PRE_TORUS_2D_STEP_TIMES = {
     "gemma3-12b|decode_32k|2pod": 0.005640601213984056,
     "gemma3-12b|decode_32k|pod": 0.01040860402796811,
     "gemma3-12b|decode_32k|v5p-pod": 0.011174433533523029,
     "gemma3-12b|decode_32k|v6e-pod": 0.005640400373059142,
-    "gemma3-12b|train_4k|2pod": 2.006239356136516,
-    "gemma3-12b|train_4k|pod": 3.9446190679731217,
-    "gemma3-12b|train_4k|v5p-pod": 5.470500259268863,
-    "gemma3-12b|train_4k|v6e-pod": 1.3299013060655531,
+    "gemma3-12b|train_4k|2pod": 2.0131965372365612,
+    "gemma3-12b|train_4k|pod": 5.312043982447061,
+    "gemma3-12b|train_4k|v5p-pod": 5.649483478591187,
+    "gemma3-12b|train_4k|v6e-pod": 1.3457369376433121,
     "mamba2-1.3b|decode_32k|2pod": 2.8364671636859875e-05,
     "mamba2-1.3b|decode_32k|pod": 5.487094327371975e-05,
     "mamba2-1.3b|decode_32k|v5p-pod": 6.465244810658441e-05,
     "mamba2-1.3b|decode_32k|v6e-pod": 2.833234691535151e-05,
     "mamba2-1.3b|train_4k|2pod": 0.2823090089153571,
-    "mamba2-1.3b|train_4k|pod": 0.2971891713601879,
-    "mamba2-1.3b|train_4k|v5p-pod": 0.4217759356538556,
-    "mamba2-1.3b|train_4k|v6e-pod": 0.09377336990569207,
+    "mamba2-1.3b|train_4k|pod": 0.3716940020409412,
+    "mamba2-1.3b|train_4k|v5p-pod": 0.4217763868538556,
+    "mamba2-1.3b|train_4k|v6e-pod": 0.09377382110569207,
     "qwen1.5-0.5b|decode_32k|2pod": 0.0016120377649572653,
     "qwen1.5-0.5b|decode_32k|pod": 0.0027855075299145302,
     "qwen1.5-0.5b|decode_32k|v5p-pod": 0.002752198992027129,
     "qwen1.5-0.5b|decode_32k|v6e-pod": 0.0016120126856368562,
-    "qwen1.5-0.5b|train_4k|2pod": 0.14174567748918163,
-    "qwen1.5-0.5b|train_4k|pod": 0.1210152587780616,
-    "qwen1.5-0.5b|train_4k|v5p-pod": 0.1652115513696153,
-    "qwen1.5-0.5b|train_4k|v6e-pod": 0.039441672748381694,
+    "qwen1.5-0.5b|train_4k|2pod": 0.14174568218918163,
+    "qwen1.5-0.5b|train_4k|pod": 0.1252018982518281,
+    "qwen1.5-0.5b|train_4k|v5p-pod": 0.1652115560696153,
+    "qwen1.5-0.5b|train_4k|v6e-pod": 0.039441677448381694,
 }
 
 
@@ -69,11 +72,11 @@ PRE_TORUS_2D_STEP_TIMES = {
 PRE_PIPELINE_STEP_TIMES = dict(PRE_TORUS_2D_STEP_TIMES)
 PRE_PIPELINE_STEP_TIMES.update({
     "gemma3-12b|decode_32k|v5p-3d": 0.011174433533523029,
-    "gemma3-12b|train_4k|v5p-3d": 4.433797577840346,
+    "gemma3-12b|train_4k|v5p-3d": 4.616045256762777,
     "mamba2-1.3b|decode_32k|v5p-3d": 6.465244810658441e-05,
-    "mamba2-1.3b|train_4k|v5p-3d": 0.4083821445427445,
+    "mamba2-1.3b|train_4k|v5p-3d": 0.4083825957427445,
     "qwen1.5-0.5b|decode_32k|v5p-3d": 0.002752198992027129,
-    "qwen1.5-0.5b|train_4k|v5p-3d": 0.159472255073319,
+    "qwen1.5-0.5b|train_4k|v5p-3d": 0.159472259773319,
 })
 
 

@@ -60,8 +60,8 @@ def test_analytical_vs_generated_plan_agreement():
 
 def test_trainer_smoke():
     from repro.configs.base import ShapeConfig
-    from repro.core.cluster import cpu_host_config
     from repro.launch.mesh import make_host_mesh
+    from repro.launch.train import mesh_cluster
     from repro.optim import adamw
     from repro.runtime.train_loop import Trainer, TrainerConfig
 
@@ -69,8 +69,7 @@ def test_trainer_smoke():
                                dtype="float32")
     shape = ShapeConfig("t", seq_len=32, global_batch=4, mode="train")
     mesh = make_host_mesh()
-    cc = cpu_host_config().with_mesh(tuple(mesh.devices.shape),
-                                     tuple(mesh.axis_names))
+    cc = mesh_cluster(mesh)
     tr = Trainer(arch, shape, cc, mesh,
                  tcfg=TrainerConfig(steps=3, log_every=1),
                  opt_cfg=adamw.AdamWConfig(total_steps=3))

@@ -9,9 +9,9 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
-from repro.core.cluster import cpu_host_config
 from repro.core.planner import ShardingPlan
 from repro.launch.mesh import make_host_mesh
+from repro.launch.train import mesh_cluster
 from repro.optim import adamw
 from repro.runtime.train_loop import Trainer, TrainerConfig
 
@@ -24,8 +24,7 @@ def _trainer(tmp_path=None, steps=12, **tkw):
     arch = dataclasses.replace(get_config("qwen1.5-0.5b").reduced(),
                                dtype="float32")
     mesh = make_host_mesh()
-    cc = cpu_host_config().with_mesh(tuple(mesh.devices.shape),
-                                     tuple(mesh.axis_names))
+    cc = mesh_cluster(mesh)
     plan = ShardingPlan(batch_axes=("data",))
     tcfg = TrainerConfig(steps=steps, log_every=1,
                          checkpoint_every=5,
