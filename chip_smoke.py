@@ -48,30 +48,18 @@ def log(msg: str) -> None:
     print(msg, flush=True)
 
 
-class CompileMeter:
-    """Seconds JAX spends compiling (or loading from the persistent cache)
-    and the persistent-cache hits, read from jax.monitoring events."""
+def compile_snapshot():
+    """Where the program's compile record stands (``repro.runtime.tracing``:
+    backend compiles or persistent-cache loads, and cache hits)."""
+    from repro.runtime import tracing
+    return time.perf_counter(), tracing.cache_hits()
 
-    def __init__(self, jax):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
 
-    def _duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return self.seconds, self.cache_hits
-
-    def since(self, snap) -> str:
-        return (f"compile_s={self.seconds - snap[0]:.3f} "
-                f"cache_hits={self.cache_hits - snap[1]}")
+def compiled_since(snap) -> str:
+    from repro.runtime import tracing
+    seconds = sum(c.seconds for c in tracing.compiles(snap[0]))
+    return (f"compile_s={seconds:.3f} "
+            f"cache_hits={tracing.cache_hits() - snap[1]}")
 
 
 def _train_shape():
@@ -80,7 +68,7 @@ def _train_shape():
                                seq_len=TRAIN_SEQ)
 
 
-def run_training(arch, shape, devices, label: str, meter: CompileMeter):
+def run_training(arch, shape, devices, label: str):
     """Plan, build and run the trainer on a host mesh over ``devices``;
     returns (losses, run result, plan)."""
     from repro.launch.mesh import make_host_mesh
@@ -94,7 +82,7 @@ def run_training(arch, shape, devices, label: str, meter: CompileMeter):
     log(f"[{label}] plan={best.plan.describe()} chip={trainer.cc.chip.name} "
         f"mesh={trainer.cc.mesh_shape} estimate_hbm={best.hbm_est:.0f}B "
         f"feasible={best.feasible}")
-    snap = meter.snapshot()
+    snap = compile_snapshot()
     t0 = time.perf_counter()
     result = trainer.run()
     wall = time.perf_counter() - t0
@@ -103,7 +91,7 @@ def run_training(arch, shape, devices, label: str, meter: CompileMeter):
     steps = [h["time_s"] for h in hist]
     log(f"[{label}] steps={len(hist)} losses={losses}")
     log(f"[{label}] first_step_s={steps[0]:.4f} (trace+compile+run) "
-        f"step_s={steps[1:]} {meter.since(snap)} wall_s={wall:.3f}")
+        f"step_s={steps[1:]} {compiled_since(snap)} wall_s={wall:.3f}")
     if len(losses) != TRAIN_STEPS or not all(map(math.isfinite, losses)):
         raise AssertionError(f"{label}: non-finite or missing losses {losses}")
     return losses, result, best.plan
@@ -113,11 +101,11 @@ def _peaks(devices):
     return [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devices]
 
 
-def phase_train(jax, meter):
+def phase_train(jax):
     from repro.configs import get_config
     arch = get_config(ARCH_ID)
     losses, _, _ = run_training(arch, _train_shape(), jax.devices()[:1],
-                                "train", meter)
+                                "train")
     log(f"[train] peak_bytes_in_use={_peaks(jax.devices()[:1])[0]} "
         f"memory_stats={jax.devices()[0].memory_stats()}")
     expect = math.log(arch.vocab_size)
@@ -126,7 +114,7 @@ def phase_train(jax, meter):
                              f"ln(vocab)={expect:.4f}")
 
 
-def phase_serve(jax, meter):
+def phase_serve(jax):
     import jax.numpy as jnp
     import numpy as np
     from repro.configs import get_config
@@ -144,7 +132,7 @@ def phase_serve(jax, meter):
                     max_new_tokens=SERVE_NEW)
             for _ in range(SERVE_REQUESTS)]
     for run in ("cold", "warm"):
-        snap = meter.snapshot()
+        snap = compile_snapshot()
         t0 = time.perf_counter()
         outs = engine.generate(reqs)
         wall = time.perf_counter() - t0
@@ -152,7 +140,7 @@ def phase_serve(jax, meter):
         log(f"[serve] {run}: requests={len(outs)} wall_s={wall:.4f} "
             f"prefill_s={c.prefill_time_s:.4f} decode_s={c.decode_time_s:.4f} "
             f"new_tokens/s={len(outs) * SERVE_NEW / wall:.1f} "
-            f"{meter.since(snap)}")
+            f"{compiled_since(snap)}")
     for c in outs:
         if len(c.tokens) != SERVE_NEW or not all(
                 0 <= t < arch.vocab_size for t in c.tokens):
@@ -178,7 +166,7 @@ def phase_serve(jax, meter):
         raise AssertionError("decode logits disagree with a fresh prefill")
 
 
-def phase_kernels(jax, meter):
+def phase_kernels(jax):
     import jax.numpy as jnp
     import numpy as np
     from repro.kernels import ops, ref
@@ -187,13 +175,13 @@ def phase_kernels(jax, meter):
     # flash attention: qwen1.5-0.5b heads at a 2048-token prefill
     shp = (4, 16, 2048, 64)
     q, k, v = (jax.random.normal(key[i], shp, jnp.bfloat16) for i in range(3))
-    snap = meter.snapshot()
+    snap = compile_snapshot()
     out = ops.flash_attention(q, k, v, bq=512, bk=512).block_until_ready()
     want = ref.flash_attention_ref(q, k, v)
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - want.astype(jnp.float32))))
     log(f"[kernels] flash_attention {shp} bf16: max_abs_err={err:.5f} "
-        f"{meter.since(snap)}")
+        f"{compiled_since(snap)}")
     if not err <= 3e-2:
         raise AssertionError(f"flash_attention error {err}")
 
@@ -205,7 +193,7 @@ def phase_kernels(jax, meter):
     bm = jax.random.normal(key[6], (b, s, 1, n), jnp.float32)
     cm = jax.random.normal(key[7], (b, s, 1, n), jnp.float32)
     d = jnp.ones((h,), jnp.float32)
-    snap = meter.snapshot()
+    snap = compile_snapshot()
     y, st = ops.ssd_scan(x, dt, a_log, bm, cm, d, chunk=256)
     y.block_until_ready()
     y_ref, st_ref = ref.ssd_scan_ref(x, dt, a_log, bm, cm, d, chunk=256)
@@ -214,18 +202,17 @@ def phase_kernels(jax, meter):
         scale = float(jnp.max(jnp.abs(exp)))
         log(f"[kernels] ssd_scan {name} {tuple(got.shape)}: "
             f"max_abs_err={err:.5f} max_abs_ref={scale:.4f} "
-            f"{meter.since(snap)}")
+            f"{compiled_since(snap)}")
         if not (np.isfinite(err) and err <= 2e-2 * max(scale, 1.0)):
             raise AssertionError(f"ssd_scan {name} error {err}")
 
 
-def phase_four_chips(jax, meter):
+def phase_four_chips(jax):
     from repro.configs import get_config
 
     arch = get_config(ARCH_ID)
     devs = jax.devices()[:4]
-    losses4, res, plan = run_training(arch, _train_shape(), devs, "2x2",
-                                      meter)
+    losses4, res, plan = run_training(arch, _train_shape(), devs, "2x2")
     log(f"[2x2] peak_bytes_in_use per device={_peaks(devs)} "
         f"memory_stats[0]={devs[0].memory_stats()}")
     leaves = jax.tree.leaves(res["params"])
@@ -241,8 +228,7 @@ def phase_four_chips(jax, meter):
         raise AssertionError("parameters are not spread over the 2x2 mesh")
     del res, leaves
 
-    losses1, _, _ = run_training(arch, _train_shape(), devs[:1], "1dev",
-                                 meter)
+    losses1, _, _ = run_training(arch, _train_shape(), devs[:1], "1dev")
     diff = max(abs(a - b) for a, b in zip(losses4, losses1))
     log(f"[2x2] max |loss_2x2 - loss_1dev| = {diff:.5f}")
     if not diff <= 2e-2:
@@ -274,7 +260,6 @@ def main() -> int:
 
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
-    meter = CompileMeter(jax)
     log(f"device platform={dev.platform} kind={dev.device_kind} "
         f"count={len(devices)} compile_cache={cache_dir}")
 
@@ -285,7 +270,7 @@ def main() -> int:
     for name, fn in phases:
         t0 = time.perf_counter()
         try:
-            fn(jax, meter)
+            fn(jax)
             log(f"== {name}: ok ({time.perf_counter() - t0:.1f}s)")
         except Exception:
             traceback.print_exc()

@@ -234,6 +234,7 @@ def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("mlp")
 def ffn(x: jax.Array, params: Dict[str, jax.Array], gated: bool,
         act: str = "silu") -> jax.Array:
     """Dense MLP. gated: SwiGLU (w_gate, w_up, w_down); else (w_up, w_down)."""
@@ -246,6 +247,7 @@ def ffn(x: jax.Array, params: Dict[str, jax.Array], gated: bool,
     return out
 
 
+@jax.named_scope("moe")
 def moe_ffn(x: jax.Array, params: Dict[str, jax.Array], *, top_k: int,
             capacity_factor: float, gated: bool,
             group_size: int = 4096) -> Tuple[jax.Array, jax.Array]:

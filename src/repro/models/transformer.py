@@ -202,6 +202,7 @@ def init_params(cfg: ArchConfig, rng) -> Params:
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("attention")
 def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                   positions: jax.Array, window: Optional[int],
                   causal: bool = True,
@@ -279,6 +280,7 @@ def _masked_dense_attention(q, k, v, mask) -> jax.Array:
     return o.reshape(b, hq, sq, dv).astype(q.dtype)
 
 
+@jax.named_scope("attention")
 def mla_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                   positions: jax.Array,
                   kv_cache: Optional[Dict[str, jax.Array]] = None,
@@ -411,6 +413,7 @@ def block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
     return x + out, new_cache, aux
 
 
+@jax.named_scope("ssd")
 def mamba_layer_apply(cfg: ArchConfig, p: Params, x: jax.Array,
                       cache: Optional[Dict] = None, use_kernel: bool = False):
     h = L.rms_norm(x, p["ln"], cfg.norm_eps)
@@ -638,6 +641,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
     return x, new_cache, aux_total
 
 
+@jax.named_scope("ce_head")
 def _head(cfg: ArchConfig, params: Params, x: jax.Array) -> jax.Array:
     h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings:
@@ -665,7 +669,8 @@ def forward_hidden(cfg: ArchConfig, params: Params, tokens: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array]:
     """Trunk only: returns (pre-head hidden [B,S_total,d], aux_loss)."""
     b, s = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.enc_dec is not None:
         assert frontend is not None, "enc-dec arch needs frontend embeddings"
         enc_out = run_encoder(cfg, params, frontend, remat, use_kernel)
@@ -750,6 +755,7 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array], *,
     return total, metrics
 
 
+@jax.named_scope("ce_head")
 def _chunked_ce(cfg: ArchConfig, params: Params, h: jax.Array,
                 targets: jax.Array, chunk: int) -> jax.Array:
     """Mean next-token CE with a rematerialized, time-chunked head.
@@ -793,7 +799,8 @@ def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
             capacity_factor: Optional[float] = None) -> Tuple[jax.Array, Cache]:
     """Fill the decode cache from a prompt; returns (last-token logits, cache)."""
     b, s = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     offset = 0
     if cfg.enc_dec is not None:
         assert frontend is not None
@@ -833,7 +840,8 @@ def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
     """One decoding step.  token: [B] int32.  Returns (logits [B,V], cache)."""
     b = token.shape[0]
     pos = cache["pos"]
-    x = jnp.take(params["embed"], token[:, None], axis=0)
+    with jax.named_scope("embed"):
+        x = jnp.take(params["embed"], token[:, None], axis=0)
     positions = jnp.broadcast_to(pos[None, None], (b, 1))
     new_cache: Cache = {"pos": pos + 1}
 

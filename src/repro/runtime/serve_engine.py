@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
 from typing import Any, Dict, List, Optional, Sequence
 
 import jax
@@ -33,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import Model
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass
@@ -104,10 +104,19 @@ class ServeEngine:
         self.temperature = config.temperature
         self.capacity_factor = config.capacity_factor
         self._rng = jax.random.PRNGKey(config.seed)
-        self._prefill = jax.jit(partial(
-            model.prefill, capacity_factor=config.capacity_factor))
-        self._decode = jax.jit(partial(
-            model.decode_step, capacity_factor=config.capacity_factor))
+        cf = config.capacity_factor
+
+        # named functions, so the compiled programs are jit_serve_prefill
+        # and jit_serve_decode in profiles and compile records
+        def serve_prefill(params, tokens, cache, frontend=None):
+            return model.prefill(params, tokens, cache, frontend,
+                                 capacity_factor=cf)
+
+        def serve_decode(params, token, cache):
+            return model.decode_step(params, token, cache, capacity_factor=cf)
+
+        self._prefill = jax.jit(serve_prefill)
+        self._decode = jax.jit(serve_decode)
         self._queue: List[_Slot] = []
         self._active: List[_Slot] = []
         self._cache: Any = None
@@ -167,7 +176,9 @@ class ServeEngine:
         logits, self._cache = self._prefill(self.params,
                                             jnp.asarray(prompts), cache,
                                             frontend)
-        tok = np.asarray(self._sample(logits))
+        tok = self._sample(logits)
+        with tracing.span("serve.prefill_wait"):
+            tok = np.asarray(tok)
         dt = time.perf_counter() - t0
         new_rids = {s.rid for s in admitted}
         for i, s in enumerate(batch):
@@ -193,7 +204,16 @@ class ServeEngine:
     def step(self, frontend: Optional[jax.Array] = None) -> List[Completion]:
         """Advance the pool one schedule tick: admit if lanes free up,
         commit each live slot's pending token, decode one token for the
-        still-running slots.  Returns the requests that finished."""
+        still-running slots.  Returns the requests that finished.
+
+        The tick is one ``serve.step`` span (:mod:`repro.runtime.tracing`,
+        ``step`` = the index of its decode) with children ``admit`` (which
+        holds ``prefill_wait``, the read-back of the first tokens),
+        ``commit``, ``decode_dispatch`` and ``decode_wait``."""
+        with tracing.span("serve.step", step=self.stats["decode_steps"]):
+            return self._step(frontend)
+
+    def _step(self, frontend: Optional[jax.Array]) -> List[Completion]:
         if self._queue and (self._cache is None
                             or any(s.done for s in self._active)
                             or len(self._active) < self._slot_budget()):
@@ -201,15 +221,17 @@ class ServeEngine:
                 raise NotImplementedError(
                     "frontend features are single-admission only: submit "
                     "all requests before the first step")
-            self._admit(frontend)
+            with tracing.span("serve.admit"):
+                self._admit(frontend)
         finished: List[Completion] = []
         if not self._active:
             return finished
-        for s in self._active:
-            if not s.done:
-                self._commit(s)
-                if s.done:
-                    finished.append(self._completion(s))
+        with tracing.span("serve.commit"):
+            for s in self._active:
+                if not s.done:
+                    self._commit(s)
+                    if s.done:
+                        finished.append(self._completion(s))
         live = [s for s in self._active if not s.done]
         if not live:
             self._active = []
@@ -218,11 +240,14 @@ class ServeEngine:
         # One lockstep decode over the whole batch; finished lanes ride
         # along as padding until the next admission compacts them, and
         # their samples are masked out of the accounting below.
-        tok = jnp.asarray(np.array([s.pending for s in self._active],
-                                   np.int32))
-        t0 = time.perf_counter()
-        logits, self._cache = self._decode(self.params, tok, self._cache)
-        nxt = np.asarray(self._sample(logits))
+        with tracing.span("serve.decode_dispatch"):
+            tok = jnp.asarray(np.array([s.pending for s in self._active],
+                                       np.int32))
+            t0 = time.perf_counter()
+            logits, self._cache = self._decode(self.params, tok, self._cache)
+            nxt = self._sample(logits)
+        with tracing.span("serve.decode_wait"):
+            nxt = np.asarray(nxt)
         dt = time.perf_counter() - t0
         self.stats["decode_steps"] += 1
         self.stats["wasted_slot_steps"] += len(self._active) - len(live)

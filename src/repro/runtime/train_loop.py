@@ -38,6 +38,7 @@ from repro.core.planner import (OVERLAP_FRACTION, ShardingPlan,
 from repro.data.pipeline import make_pipeline
 from repro.models.model import Model, build_model
 from repro.optim import adamw, compress
+from repro.runtime import tracing
 from repro.runtime.straggler import StepTimeMonitor, decide_remesh
 
 
@@ -77,10 +78,11 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
         else:
             (loss, metrics), grads = grad_fn(params, batch)
 
-        grads, ef_state = compress.compress_grads(grads, ef_state,
-                                                  compress_scheme)
-        new_params, new_opt, opt_metrics = adamw.apply(opt_cfg, opt_state,
-                                                       grads, params)
+        with jax.named_scope("optimizer"):
+            grads, ef_state = compress.compress_grads(grads, ef_state,
+                                                      compress_scheme)
+            new_params, new_opt, opt_metrics = adamw.apply(opt_cfg, opt_state,
+                                                           grads, params)
         out_metrics = {"loss": loss, **opt_metrics,
                        **{k: v for k, v in metrics.items()}}
         return new_params, new_opt, ef_state, out_metrics
@@ -337,6 +339,12 @@ class Trainer:
 
     def run(self, *, start_step: int = 0, params=None, opt_state=None,
             ef=None, on_metrics: Optional[Callable] = None) -> Dict[str, Any]:
+        """Train from ``start_step`` until ``tcfg.steps``.  Each step is one
+        ``train.step`` span (:mod:`repro.runtime.tracing`) whose children
+        are ``input_wait``, ``device_put``, ``dispatch``, ``device_wait``
+        (the metrics' read-back, which waits for the step to end),
+        ``observe`` and, when it fires, ``checkpoint``; ``time_s`` runs from
+        the end of ``input_wait`` to the end of ``device_wait``."""
         if params is None:
             params, opt_state, ef = self.init_state()
             params, opt_state, start_step = self.maybe_resume(params, opt_state)
@@ -345,32 +353,43 @@ class Trainer:
                              self.shape.global_batch, seed=self.tcfg.seed,
                              frontend_shape=fshape, start_step=start_step)
         history = []
+        gstep = start_step
         try:
             with self.mesh:
-                for gstep, batch in pipe:
-                    if gstep >= self.tcfg.steps:
-                        break
-                    t0 = time.perf_counter()
-                    batch = {k: jax.device_put(v, self.batch_sh[k])
-                             for k, v in batch.items()}
-                    params, opt_state, ef, metrics = self.train_step(
-                        params, opt_state, ef, batch)
-                    metrics = {k: float(v) for k, v in metrics.items()}
-                    dt = time.perf_counter() - t0
-                    self.monitor.record({0: dt})
-                    if self.recalibrator is not None:
-                        # observation only: acting on a replan (restore
-                        # under new shardings) stays with the caller, who
-                        # reads .events / the returned history
-                        self.recalibrator.observe(dt, step=gstep)
-                    if gstep % self.tcfg.log_every == 0:
-                        history.append({"step": gstep, "time_s": dt, **metrics})
-                        if on_metrics:
-                            on_metrics(history[-1])
-                    if (self.checkpointer and gstep > 0
-                            and gstep % self.tcfg.checkpoint_every == 0):
-                        self.checkpointer.save(
-                            gstep, {"params": params, "opt": opt_state})
+                # tcfg.steps is read each step: on_metrics may lower it
+                while gstep < self.tcfg.steps:
+                    with tracing.step_span("train.step", step_num=gstep):
+                        with tracing.span("train.input_wait"):
+                            gstep, batch = next(pipe)
+                        t0 = time.perf_counter()
+                        with tracing.span("train.device_put"):
+                            batch = {k: jax.device_put(v, self.batch_sh[k])
+                                     for k, v in batch.items()}
+                        with tracing.span("train.dispatch"):
+                            params, opt_state, ef, metrics = self.train_step(
+                                params, opt_state, ef, batch)
+                        with tracing.span("train.device_wait"):
+                            metrics = {k: float(v) for k, v in metrics.items()}
+                        dt = time.perf_counter() - t0
+                        with tracing.span("train.observe"):
+                            self.monitor.record({0: dt})
+                            if self.recalibrator is not None:
+                                # observation only: acting on a replan
+                                # (restore under new shardings) stays with
+                                # the caller, who reads .events / the
+                                # returned history
+                                self.recalibrator.observe(dt, step=gstep)
+                            if gstep % self.tcfg.log_every == 0:
+                                history.append({"step": gstep, "time_s": dt,
+                                                **metrics})
+                                if on_metrics:
+                                    on_metrics(history[-1])
+                        if (self.checkpointer and gstep > 0
+                                and gstep % self.tcfg.checkpoint_every == 0):
+                            with tracing.span("train.checkpoint"):
+                                self.checkpointer.save(
+                                    gstep, {"params": params, "opt": opt_state})
+                    gstep += 1
         finally:
             pipe.close()
             if self.checkpointer:
