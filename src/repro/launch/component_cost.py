@@ -103,13 +103,16 @@ def _cache_slice_specs(mesh, plan, shapes: Dict[str, Any]):
         if key == "kpos":
             out[key] = _sds(shp, sds.dtype, _ns(mesh))
             continue
+        if key == "kv":  # [cap, H, B, 2*hd]
+            b = _guarded(mesh, shp[2], plan.batch_axes)
+            s = _guarded(mesh, shp[0], plan.batch_axes) if b is None else None
+            out[key] = _sds(shp, sds.dtype, _ns(
+                mesh, s, _guarded(mesh, shp[1], plan.tp_axes), b, None))
+            continue
         b = _guarded(mesh, shp[0], plan.batch_axes)
-        if nd == 4:      # [B, H, cap, hd] kv  / [B, H, P, N] ssm state
+        if nd == 4:      # [B, H, P, N] ssm state
             h = _guarded(mesh, shp[1], plan.tp_axes)
-            s = None
-            if b is None and key in ("k", "v"):
-                s = _guarded(mesh, shp[2], plan.batch_axes)
-            out[key] = _sds(shp, sds.dtype, _ns(mesh, b, h, s, None))
+            out[key] = _sds(shp, sds.dtype, _ns(mesh, b, h, None, None))
         elif nd == 3:    # [B, S, r] mla latent / [B, W-1, C] conv
             s = None
             if b is None and key in ("ckv", "krope"):

@@ -126,7 +126,8 @@ def batch_shardings(mesh: Mesh, plan: ShardingPlan, batch_shapes: Any) -> Any:
 
 
 def cache_shardings(mesh: Mesh, plan: ShardingPlan, cache_shapes: Any) -> Any:
-    """Decode caches: [L, B, H, S, D]-style — batch over data, heads over tp."""
+    """Decode caches: batch over data, heads over tp.  Self-attention caches
+    are slot-major [L, S, H, B, 2D] (``kv``)."""
     b_axes = tuple(a for a in plan.batch_axes if a in mesh.shape)
     tp = tuple(a for a in plan.tp_axes if a in mesh.shape)
 
@@ -136,7 +137,12 @@ def cache_shardings(mesh: Mesh, plan: ShardingPlan, cache_shapes: Any) -> Any:
         shape = leaf.shape
         if key.endswith("pos") or "kpos" in key:
             return _ns(mesh)
-        if nd == 5:        # [L, B, H, S, D] kv / [L, B, H, P, N] ssm state
+        if _pstr(path[-1]) == "kv":     # [L, S, H, B, 2D]
+            bg = _guard(mesh, shape[3], b_axes)
+            # batch not shardable (e.g. long_500k B=1): shard KV length
+            sg = _guard(mesh, shape[1], b_axes) if bg is None else None
+            return _ns(mesh, None, sg, _guard(mesh, shape[2], tp), bg, None)
+        if nd == 5:        # [L, B, H, S, D] cross K/V / [L, B, H, P, N] ssm state
             bg = _guard(mesh, shape[1], b_axes)
             sg = None
             if bg is None and "state" not in key:

@@ -52,6 +52,11 @@ class Model:
         return T.prefill(self.cfg, params, tokens, cache, frontend,
                          use_kernel=use_kernel, capacity_factor=capacity_factor)
 
+    @property
+    def decode_carries_cache(self) -> bool:
+        """The decode writes its K/V rows into the stacked cache in place."""
+        return T.decode_carries_cache(self.cfg)
+
     def decode_step(self, params, token, cache, *, use_kernel: bool = False,
                     capacity_factor=None):
         return T.decode_step(self.cfg, params, token, cache,

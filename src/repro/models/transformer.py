@@ -212,7 +212,8 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """Standard GQA attention.  x: [B,S,d].
 
-    kv_cache: {"k","v": [B,Hkv,S_c,hd], "kpos": [S_c]} — ring or full.
+    kv_cache: {"kv": [S_c,Hkv,B,2*hd], "kpos": [S_c]} — ring or full; each
+    slot's K and V side by side (see :func:`init_cache`).
     kv_source: cross-attention source (whisper); disables rope+cache-write.
     """
     b, s, d = x.shape
@@ -233,35 +234,60 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
             out = L.attention(q, k, v, causal=causal, window=window,
                               use_kernel=use_kernel)
         else:
-            ck, cv, kpos = kv_cache["k"], kv_cache["v"], kv_cache["kpos"]
-            cap = ck.shape[2]
+            kv, kpos = kv_cache["kv"], kv_cache["kpos"]
+            # "layer": the whole stacked cache rides the layers
+            # (_at_layer); this layer's rows are kv[layer]
+            layer = kv_cache.get("layer")
+            at = () if layer is None else (layer,)
+            cap = kv.shape[len(at)]
+            # one slot's row holds K and V side by side: [Hkv, B, 2*hd]
+            rows = jnp.concatenate([k, v], axis=-1).transpose(
+                2, 1, 0, 3).astype(kv.dtype)
             if s == 1:                                     # decode
                 pos = positions[0, 0]
                 slot = pos % cap
-                ck = jax.lax.dynamic_update_slice_in_dim(ck, k, slot, axis=2)
-                cv = jax.lax.dynamic_update_slice_in_dim(cv, v, slot, axis=2)
-                kpos = jax.lax.dynamic_update_slice_in_dim(
-                    kpos, pos[None].astype(kpos.dtype), slot, axis=0)
+                kv = _put(kv, rows, at + (slot, 0, 0, 0))
+                kpos = _put(kpos, pos[None].astype(kpos.dtype), at + (slot,))
+                new_cache = {"kv": kv, "kpos": kpos}
+                if layer is not None:
+                    kpos = jax.lax.dynamic_index_in_dim(kpos, layer,
+                                                        keepdims=False)
                 valid = (kpos >= 0) & (kpos <= pos)
                 if window is not None:
                     valid &= kpos > pos - window
                 scores_mask = valid[None, None, None, :]
+                ck, cv = (_cached_heads(kv, at, i * hd, hd) for i in (0, 1))
                 out = _masked_dense_attention(q, ck, cv, scores_mask)
             else:                                          # prefill
                 if s >= cap:
-                    ck = k[:, :, s - cap:]
-                    cv = v[:, :, s - cap:]
+                    kv = rows[s - cap:]
                     kpos = positions[0, s - cap:].astype(jnp.int32)
                 else:
-                    ck = jax.lax.dynamic_update_slice_in_dim(ck, k, 0, axis=2)
-                    cv = jax.lax.dynamic_update_slice_in_dim(cv, v, 0, axis=2)
+                    kv = jax.lax.dynamic_update_slice_in_dim(kv, rows, 0,
+                                                             axis=0)
                     kpos = jax.lax.dynamic_update_slice_in_dim(
                         kpos, positions[0].astype(jnp.int32), 0, axis=0)
                 out = L.attention(q, k, v, causal=causal, window=window,
                                   use_kernel=use_kernel)
-            new_cache = {"k": ck, "v": cv, "kpos": kpos}
+                new_cache = {"kv": kv, "kpos": kpos}
     out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
     return L.dense(out, p["w_o"]), new_cache
+
+
+def _put(buf: jax.Array, row: jax.Array, idx: Tuple) -> jax.Array:
+    """``buf`` with ``row`` (lacking ``buf``'s leading dims) written at
+    ``idx``."""
+    row = row.reshape((1,) * (buf.ndim - row.ndim) + row.shape)
+    return jax.lax.dynamic_update_slice(buf, row, idx)
+
+
+def _cached_heads(kv: jax.Array, at: Tuple, lane: int, hd: int) -> jax.Array:
+    """K (``lane`` 0) or V (``lane`` hd) of the cache's layer ``at`` as
+    [B,Hkv,S_c,hd].  K and V are sliced apart, so each slice fuses into the
+    one attention product that reads it instead of being materialized."""
+    size = (1,) * len(at) + kv.shape[len(at):-1] + (hd,)
+    x = jax.lax.dynamic_slice(kv, at + (0, 0, 0, lane), size)
+    return x.reshape(x.shape[len(at):]).transpose(2, 1, 0, 3)
 
 
 def _masked_dense_attention(q, k, v, mask) -> jax.Array:
@@ -433,7 +459,11 @@ def _remat_wrap(fn, remat: str):
 
 def scan_stack(stacked: Params, x: jax.Array, body_fn, cache=None,
                remat: str = "none"):
-    """Scan a homogeneous layer stack.  body_fn(p, h, c) -> (h, c, aux)."""
+    """Scan a homogeneous layer stack.  body_fn(p, h, c) -> (h, c, aux).
+
+    A one-token step carries stacked K/V caches (a ``{"kv", "kpos"}`` dict
+    or a tuple of them) whole (:func:`_scan_in_place`); other caches are
+    scanned as ``xs`` and restacked as ``ys``."""
     if cache is None:
         def body(h, p):
             h2, _, aux = body_fn(p, h, None)
@@ -441,6 +471,10 @@ def scan_stack(stacked: Params, x: jax.Array, body_fn, cache=None,
         body = _remat_wrap(body, remat)
         x, auxs = jax.lax.scan(body, x, stacked)
         return x, None, auxs.sum()
+
+    caches = cache if isinstance(cache, tuple) else (cache,)
+    if x.shape[1] == 1 and all(set(c) == {"kv", "kpos"} for c in caches):
+        return _scan_in_place(stacked, x, body_fn, cache)
 
     def body(h, pc):
         p, c = pc
@@ -451,19 +485,57 @@ def scan_stack(stacked: Params, x: jax.Array, body_fn, cache=None,
     return x, cache2, auxs.sum()
 
 
+def decode_carries_cache(cfg: ArchConfig) -> bool:
+    """Whether a one-token decode of ``cfg`` writes K/V rows into stacked
+    caches in place (:func:`_at_layer`): every family with attention
+    caches; MLA keeps latents and the SSM its state."""
+    return cfg.family != "ssm" and cfg.mla is None
+
+
+def _at_layer(cache, i):
+    """Layer ``i``'s view of stacked K/V caches (one ``{"kv", "kpos"}``
+    dict or a tuple of them): the whole stack, which gqa_attention reads
+    at ``[i]`` and writes one row of in place."""
+    if isinstance(cache, tuple):
+        return tuple(dict(c, layer=i) for c in cache)
+    return dict(cache, layer=i)
+
+
+def _scan_in_place(stacked: Params, x: jax.Array, body_fn, cache):
+    """One-token step over stacked K/V caches: the scan carries them whole
+    and each layer writes its row at ``[layer, slot]``, so a cache is
+    neither sliced out as ``xs`` nor restacked as ``ys``."""
+    n = jax.tree.leaves(stacked)[0].shape[0]
+
+    def body(carry, pi):
+        h, c = carry
+        p, i = pi
+        h2, c2, aux = body_fn(p, h, _at_layer(c, i))
+        return (h2, c2), aux
+
+    (x, cache2), auxs = jax.lax.scan(body, (x, cache),
+                                     (stacked, jnp.arange(n)))
+    return x, cache2, auxs.sum()
+
+
 # ---------------------------------------------------------------------------
 # Cache construction
 # ---------------------------------------------------------------------------
 
 
 def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Cache:
-    """Concrete zero-filled decode cache (eval_shape-able for the dry-run)."""
+    """Concrete zero-filled decode cache (eval_shape-able for the dry-run).
+
+    An attention cache is slot-major, ``"kv"`` [layers, slots, Hkv, B,
+    2*hd] with each slot's K and V side by side, so a one-token step writes
+    one contiguous row per layer, and the chip's default layout (slots
+    major, batch x K|V minor; lane-dense at hd 64) is the one the decode's
+    attention reads: no relayout copy, no custom layout to keep."""
     dtype = jnp.dtype(cfg.dtype)
     nkv, hd = cfg.n_kv_heads, cfg.head_dim_
 
     def kvc(n_layers, cap):
-        return {"k": jnp.zeros((n_layers, batch, nkv, cap, hd), dtype),
-                "v": jnp.zeros((n_layers, batch, nkv, cap, hd), dtype),
+        return {"kv": jnp.zeros((n_layers, cap, nkv, batch, 2 * hd), dtype),
                 "kpos": jnp.full((n_layers, cap), -1, jnp.int32)}
 
     cache: Cache = {"pos": jnp.zeros((), jnp.int32)}
@@ -498,11 +570,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Cache:
         period = len(cfg.window_pattern)
         n_cycles = cfg.n_layers // period
         for i, w in enumerate(cfg.window_pattern):
-            cap = max_len if w is None else min(w, max_len)
-            cache[f"p{i}"] = {
-                "k": jnp.zeros((n_cycles, batch, nkv, cap, hd), dtype),
-                "v": jnp.zeros((n_cycles, batch, nkv, cap, hd), dtype),
-                "kpos": jnp.full((n_cycles, cap), -1, jnp.int32)}
+            cache[f"p{i}"] = kvc(n_cycles,
+                                 max_len if w is None else min(w, max_len))
     elif cfg.moe is not None:
         nd = cfg.moe.first_dense_layers
         if nd:
@@ -541,6 +610,10 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
         mamba_stack = jax.tree.map(
             lambda a: a.reshape((n_seg, every) + a.shape[1:]), params["blocks"])
         mcaches, acaches = [], []
+        attn = cache["attn"] if cache is not None else None
+        # a one-token step writes its rows into the shared-attention stack
+        # in place (_at_layer); a prompt fills each application's slice
+        in_place = cache is not None and x.shape[1] == 1
 
         def body(p, h, c):
             return mamba_layer_apply(cfg, p, h, c, use_kernel)
@@ -553,23 +626,22 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
             if cache is not None:
                 mcaches.append(c2)
             shared = params["shared_attn"][seg % len(params["shared_attn"])]
-            a_cache = (jax.tree.map(lambda a: a[seg], cache["attn"])
-                       if cache is not None else None)
             blk = _remat_wrap(
                 lambda h_, ac_, _sh=shared: block_apply(
                     cfg, _sh, h_, positions=positions, window=None,
                     kv_cache=ac_, use_kernel=use_kernel)[:2],
                 remat if cache is None else "none")
             if cache is None:
-                x2, _ = blk(x, None)
-                x = x2
+                x, _ = blk(x, None)
+            elif in_place:
+                x, attn = blk(x, _at_layer(attn, seg))
             else:
-                x, ac2 = blk(x, a_cache)
+                x, ac2 = blk(x, jax.tree.map(lambda a: a[seg], attn))
                 acaches.append(ac2)
         if cache is not None:
             new_cache["mamba"] = jax.tree.map(
                 lambda *xs: jnp.concatenate(xs, axis=0), *mcaches)
-            new_cache["attn"] = jax.tree.map(
+            new_cache["attn"] = attn if in_place else jax.tree.map(
                 lambda *xs: jnp.stack(xs, axis=0), *acaches)
 
     elif cfg.enc_dec is not None:
@@ -612,21 +684,15 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
                 new_c.append(c2 if c2 is not None else 0)
             return h, (tuple(new_c) if cyc_caches is not None else None, aux)
 
-        cyc_stack = params["cycles"]
-        if cache is None:
-            def body(h, p):
-                h2, (_, aux) = cycle_body(h, (p, None))
-                return h2, aux
-            body = _remat_wrap(body, remat)
-            x, auxs = jax.lax.scan(body, x, cyc_stack)
-            aux_total += auxs.sum()
-        else:
-            caches_in = tuple(cache[f"p{i}"] for i in range(period))
-            def body(h, pc):
-                h2, (cs, aux) = cycle_body(h, pc)
-                return h2, (cs, aux)
-            x, (cs_out, auxs) = jax.lax.scan(body, x, (cyc_stack, caches_in))
-            aux_total += auxs.sum()
+        def body(p, h, cs):
+            h2, (cs2, aux) = cycle_body(h, (p, cs))
+            return h2, cs2, aux
+        caches_in = (tuple(cache[f"p{i}"] for i in range(period))
+                     if cache is not None else None)
+        x, cs_out, aux = scan_stack(params["cycles"], x, body, caches_in,
+                                    remat)
+        aux_total += aux
+        if cache is not None:
             for i in range(period):
                 new_cache[f"p{i}"] = cs_out[i]
     else:
@@ -846,15 +912,14 @@ def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
     new_cache: Cache = {"pos": pos + 1}
 
     if cfg.enc_dec is not None:
-        def body(h, pc):
-            p, c, ck, cv = pc
-            h2, c2, _ = block_apply(cfg, p, h, positions=positions, window=None,
-                                    kv_cache=c, cross_state=(ck, cv),
-                                    use_kernel=use_kernel)
-            return h2, c2
-        x, self_c = jax.lax.scan(
-            body, x, (params["blocks"], cache["self"],
-                      cache["cross_k"], cache["cross_v"]))
+        def body(pc, h, c):
+            p, ck, cv = pc
+            return block_apply(cfg, p, h, positions=positions, window=None,
+                               kv_cache=c, cross_state=(ck, cv),
+                               use_kernel=use_kernel)
+        x, self_c, _ = scan_stack(
+            (params["blocks"], cache["cross_k"], cache["cross_v"]), x, body,
+            cache["self"])
         new_cache["self"] = self_c
         new_cache["cross_k"], new_cache["cross_v"] = cache["cross_k"], cache["cross_v"]
     else:

@@ -19,7 +19,9 @@ alongside the newcomers (prefill/decode equivalence makes the greedy
 continuation exact); a production engine would scatter the live KV rows
 instead, but this reference engine keeps the cache dense and the code
 honest about it.  The decode step is one jit-compiled executable — the
-`serve_step` the dry-run lowers at production shapes.
+`serve_step` the dry-run lowers at production shapes.  It takes the cache
+donated; ``stats["decode_in_place"]`` counts the steps whose decode writes
+its K/V rows where they lie (``Model.decode_carries_cache``).
 """
 from __future__ import annotations
 
@@ -107,21 +109,26 @@ class ServeEngine:
         cf = config.capacity_factor
 
         # named functions, so the compiled programs are jit_serve_prefill
-        # and jit_serve_decode in profiles and compile records
-        def serve_prefill(params, tokens, cache, frontend=None):
+        # and jit_serve_decode in profiles and compile records; the prefill
+        # builds its batch's cache, so no zero cache is held beside it
+        def serve_prefill(params, tokens, frontend=None):
+            cache = model.init_cache(tokens.shape[0], config.max_len)
             return model.prefill(params, tokens, cache, frontend,
                                  capacity_factor=cf)
 
         def serve_decode(params, token, cache):
             return model.decode_step(params, token, cache, capacity_factor=cf)
 
+        # each step's cache replaces the last, so the decode takes it
+        # donated and updates it where it lies
         self._prefill = jax.jit(serve_prefill)
-        self._decode = jax.jit(serve_decode)
+        self._decode = jax.jit(serve_decode, donate_argnums=2)
         self._queue: List[_Slot] = []
         self._active: List[_Slot] = []
         self._cache: Any = None
         self._next_rid = 0
         self.stats: Dict[str, int] = {"decode_steps": 0,
+                                      "decode_in_place": 0,
                                       "admission_rounds": 0,
                                       "wasted_slot_steps": 0}
 
@@ -171,11 +178,10 @@ class ServeEngine:
         prompts = np.zeros((len(batch), plen), np.int32)
         for i, h in enumerate(hists):               # left-pad
             prompts[i, plen - len(h):] = h
-        cache = self.model.init_cache(len(batch), self.config.max_len)
+        self._cache = None          # never hold two caches at once
         t0 = time.perf_counter()
         logits, self._cache = self._prefill(self.params,
-                                            jnp.asarray(prompts), cache,
-                                            frontend)
+                                            jnp.asarray(prompts), frontend)
         tok = self._sample(logits)
         with tracing.span("serve.prefill_wait"):
             tok = np.asarray(tok)
@@ -250,6 +256,7 @@ class ServeEngine:
             nxt = np.asarray(nxt)
         dt = time.perf_counter() - t0
         self.stats["decode_steps"] += 1
+        self.stats["decode_in_place"] += self.model.decode_carries_cache
         self.stats["wasted_slot_steps"] += len(self._active) - len(live)
         for i, s in enumerate(self._active):
             if not s.done:

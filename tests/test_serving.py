@@ -144,3 +144,74 @@ def test_engine_masks_finished_slots_and_reports_per_request_decode():
     assert out[1].tokens == base[1].tokens         # unaffected neighbour
     assert engine2.stats["wasted_slot_steps"] > 0
     assert out[0].decode_time_s < out[1].decode_time_s
+
+
+@pytest.mark.parametrize("arch_id", [
+    "qwen1.5-0.5b",                      # dense: one stacked K/V cache
+    "phi3.5-moe-42b-a6.6b",              # MoE stack: the "moe" K/V cache
+    "mamba2-1.3b",                       # SSM state: no K/V cache to carry
+])
+def test_engine_decode_donates_and_writes_cache_in_place(arch_id):
+    """Decode steps through the engine give forward's greedy tokens, and
+    the cache handed to each decode is donated (its buffers are deleted).
+    Only stacked K/V caches are written in place, and only their steps
+    count as ``decode_in_place``."""
+    from repro.models import transformer as T
+
+    cfg = _tiny(arch_id)
+    model = build_model(cfg)
+    params = model.init(RNG)
+    cf = float(cfg.moe.n_experts) if cfg.moe else None     # dropless
+    engine = ServeEngine(model, params,
+                         EngineConfig(max_len=32, capacity_factor=cf))
+    prompts = [[5, 6, 7, 8], [9, 10, 11, 12]]
+    rids = [engine.submit(Request(prompt=p, max_new_tokens=6))
+            for p in prompts]
+    done, donated = {}, []
+    while engine.pending_requests:
+        before, steps = engine._cache, engine.stats["decode_steps"]
+        done.update((c.rid, c) for c in engine.step())
+        if before is not None and engine.stats["decode_steps"] > steps:
+            donated.append(all(a.is_deleted() for a in jax.tree.leaves(before)))
+    assert donated and all(donated)
+    steps = engine.stats["decode_steps"]
+    assert steps == 5
+    in_place = arch_id != "mamba2-1.3b"
+    assert engine.stats["decode_in_place"] == (steps if in_place else 0)
+    for rid, prompt in zip(rids, prompts):
+        seq = prompt + done[rid].tokens
+        logits, _ = T.forward(cfg, params, jnp.asarray([seq]),
+                              capacity_factor=cf)
+        greedy = np.asarray(jnp.argmax(logits[0], axis=-1))
+        assert done[rid].tokens == greedy[len(prompt) - 1:-1].tolist()
+
+
+def _value_shapes(jaxpr):
+    """Shapes of every value ``jaxpr`` and its sub-jaxprs compute or take
+    in."""
+    out = [tuple(v.aval.shape) for v in jaxpr.invars]
+    for eqn in jaxpr.eqns:
+        out += [tuple(v.aval.shape) for v in eqn.outvars]
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            out += _value_shapes(sub)
+    return out
+
+
+@pytest.mark.parametrize("arch_id", [
+    "qwen1.5-0.5b", "phi3.5-moe-42b-a6.6b", "deepseek-v3-671b", "gemma3-12b",
+    "mamba2-1.3b", "zamba2-2.7b", "whisper-small"])
+def test_decode_writes_every_kv_stack_in_place(arch_id):
+    """``Model.decode_carries_cache`` holds for every family with stacked
+    ``"kv"`` caches, and the traced decode never holds one layer's slice
+    of them: each layer reads and writes the whole stack in place."""
+    model = build_model(_tiny(arch_id))
+    cache = model.cache_shapes(2, 32)
+    kv = [leaf.shape for path, leaf in
+          jax.tree_util.tree_flatten_with_path(cache)[0]
+          if getattr(path[-1], "key", None) == "kv"]
+    assert bool(kv) == model.decode_carries_cache
+    jaxpr = jax.make_jaxpr(model.decode_step)(
+        model.init_shapes(), jax.ShapeDtypeStruct((2,), jnp.int32), cache)
+    slices = {sh[1:] for sh in kv} | {(1,) + sh[1:] for sh in kv}
+    held = slices & set(_value_shapes(jaxpr.jaxpr))
+    assert not held, held

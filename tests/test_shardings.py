@@ -62,11 +62,31 @@ def test_batch_sharding_divides_batch_dim():
 
 def test_cache_seq_fallback_when_batch_unshardable():
     # long_500k: batch=1 -> KV length dim takes the data axis
-    shapes = {"self": {"k": jax.ShapeDtypeStruct((48, 1, 8, 524288, 256),
-                                                 jnp.bfloat16)}}
+    shapes = {"self": {"kv": jax.ShapeDtypeStruct((48, 524288, 8, 1, 512),
+                                                  jnp.bfloat16)}}
     sh = S.cache_shardings(MESH, PLAN_TP, shapes)
-    assert sh["self"]["k"].spec[1] is None
-    assert sh["self"]["k"].spec[3] == "data"
+    assert sh["self"]["kv"].spec[3] is None
+    assert sh["self"]["kv"].spec[1] == "data"
+
+
+@pytest.mark.parametrize("arch_id", [
+    "qwen1.5-0.5b", "deepseek-v3-671b", "phi3.5-moe-42b-a6.6b", "gemma3-12b",
+    "mamba2-1.3b", "zamba2-2.7b", "whisper-small"])
+def test_cache_specs_match_each_leaf_rank(arch_id):
+    """No decode cache leaf gets more spec entries than it has dimensions;
+    the MLA latents ([L, B, S, r], named ``ckv``) keep batch over data and
+    the slot-major ``kv`` [L, S, H, B, 2D] shards its batch dim."""
+    shapes = build_model(get_config(arch_id)).cache_shapes(32, 4096)
+    sh = S.cache_shardings(MESH, PLAN_TP, shapes)
+    leaves = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    specs = _flat_specs(sh)
+    for path, leaf in leaves:
+        key = "/".join(S._pstr(p) for p in path)
+        assert len(specs[key]) <= leaf.ndim, (key, leaf.shape, specs[key])
+        if key.endswith("ckv"):
+            assert specs[key][1] == "data", (key, specs[key])
+        if key.endswith("/kv"):
+            assert specs[key][3] == "data", (key, specs[key])
 
 
 def test_zero1_moments_pick_up_data_axis():

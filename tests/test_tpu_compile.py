@@ -11,6 +11,7 @@ and pytest-xdist workers must all collect the same tests.
 """
 import dataclasses
 import os
+import re
 from functools import partial
 
 import jax
@@ -19,7 +20,7 @@ import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro.configs import SHAPES, get_config
-from repro.core.cluster import local_cluster_config
+from repro.core.cluster import TPU_V5E, local_cluster_config
 from repro.core.planner import choose_plan
 from repro.kernels import flash_attention as fa
 from repro.kernels import matmul_epilogue as mme
@@ -137,3 +138,61 @@ def test_one_chip_train_step_fits_and_estimate_bounds_it(topo, batch, remat):
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     est = estimate_hbm(arch, shape, plan, cc)
     assert used <= est <= 1.5 * used, (plan.describe(), used, est)
+
+
+SERVE_BATCH, SERVE_MAX_LEN, SERVE_PROMPT = 32, 1280, 1024
+
+
+@pytest.fixture(scope="module")
+def serve_engine(one_chip):
+    """ServeEngine for qwen1.5-0.5b at the serving cell's size (32 lanes,
+    1280 cache slots), its weights given as shapes on one v5e."""
+    from repro.models.model import build_model
+    from repro.runtime.serve_engine import EngineConfig, ServeEngine
+
+    model = build_model(get_config("qwen1.5-0.5b"))
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip),
+                          model.init_shapes())
+    return ServeEngine(model, params, EngineConfig(max_len=SERVE_MAX_LEN))
+
+
+def _used_bytes(mem):
+    return (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+
+
+def test_serve_decode_keeps_kv_cache_in_place(serve_engine, one_chip):
+    """The decode writes each layer's new K/V row into the donated cache
+    where it lies: no copy of a layer's or the stack's K/V cache, the
+    cache aliased from input to output, and almost no temporaries."""
+    engine = serve_engine
+    assert engine.model.decode_carries_cache
+    cache = jax.tree.map(
+        lambda s: _sds(s.shape, s.dtype, one_chip),
+        engine.model.cache_shapes(SERVE_BATCH, SERVE_MAX_LEN))
+    token = _sds((SERVE_BATCH,), jnp.int32, one_chip)
+    compiled = engine._decode.lower(engine.params, token, cache).compile()
+    # a layer's K/V [32,16,1280,64] in any axis order, or its slot-major
+    # rows [1280,16,32,128], alone or stacked
+    kv_dims = [sorted((SERVE_BATCH, 16, SERVE_MAX_LEN, 64)),
+               sorted((SERVE_MAX_LEN, 16, SERVE_BATCH, 128))]
+    copies = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(",
+                        compiled.as_text())
+    assert copies, "no copy at all: the pattern no longer matches the text"
+    cache_copies = [c for c in copies if c.count(",") >= 3
+                    and sorted(map(int, c.split(",")[-4:])) in kv_dims]
+    assert not cache_copies, cache_copies
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert _used_bytes(mem) <= TPU_V5E.hbm_bytes
+
+
+def test_serve_prefill_fits_one_chip(serve_engine, one_chip):
+    """The prefill of 32 prompts of 1024 tokens, which builds the batch's
+    cache, fits one v5e's HBM."""
+    engine = serve_engine
+    tokens = _sds((SERVE_BATCH, SERVE_PROMPT), jnp.int32, one_chip)
+    compiled = engine._prefill.lower(engine.params, tokens).compile()
+    assert _used_bytes(compiled.memory_analysis()) <= TPU_V5E.hbm_bytes
