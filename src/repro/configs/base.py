@@ -105,6 +105,14 @@ class ArchConfig:
     frontend_seq: int = 0            # encoder frames / image patches
     mtp_depth: int = 0               # deepseek multi-token prediction heads
     dtype: str = "bfloat16"
+    # the layer scan carries the residual stream in float32 and hands each
+    # mixer its normed input in ``dtype`` (mamba_ssm's residual_in_fp32)
+    residual_in_fp32: bool = False
+
+    def __post_init__(self):
+        if self.residual_in_fp32 and self.family != "ssm":
+            raise ValueError(f"{self.name}: residual_in_fp32 is implemented "
+                             f"for the ssm family only, not {self.family!r}")
 
     # ------------------------------------------------------------- derived
     @property

@@ -163,40 +163,45 @@ def mamba_block_apply(params: Dict[str, jax.Array], x: jax.Array, ssm,
     h = ssm.n_heads(d)
     g, n = ssm.n_groups, ssm.state_size
 
-    proj = dense_(x, params["w_in"])                   # [B,S,2di+2gn+h]
-    z, xin, Bx, Cx, dt = jnp.split(
-        proj, [di, 2 * di, 2 * di + g * n, 2 * di + 2 * g * n], axis=-1)
-    conv_in = jnp.concatenate([xin, Bx, Cx], axis=-1)
-    conv_state = cache.get("conv") if cache else None
-    conv_out, new_conv = _causal_conv(conv_in, params["conv_w"],
-                                      params["conv_b"], conv_state)
-    xin, Bx, Cx = jnp.split(conv_out, [di, di + g * n], axis=-1)
-    xh = xin.reshape(bsz, s, h, ssm.head_dim)
-    Bh = Bx.reshape(bsz, s, g, n)
-    Ch = Cx.reshape(bsz, s, g, n)
-    dt = jax.nn.softplus(dt.astype(jnp.float32)
-                         + params["dt_bias"][None, None, :])
+    with jax.named_scope("in_proj"):
+        proj = dense_(x, params["w_in"])               # [B,S,2di+2gn+h]
+    with jax.named_scope("conv"):
+        z, xin, Bx, Cx, dt = jnp.split(
+            proj, [di, 2 * di, 2 * di + g * n, 2 * di + 2 * g * n], axis=-1)
+        conv_in = jnp.concatenate([xin, Bx, Cx], axis=-1)
+        conv_state = cache.get("conv") if cache else None
+        conv_out, new_conv = _causal_conv(conv_in, params["conv_w"],
+                                          params["conv_b"], conv_state)
+        xin, Bx, Cx = jnp.split(conv_out, [di, di + g * n], axis=-1)
+        xh = xin.reshape(bsz, s, h, ssm.head_dim)
+        Bh = Bx.reshape(bsz, s, g, n)
+        Ch = Cx.reshape(bsz, s, g, n)
 
-    if cache is not None and s == 1:
-        y, new_state = ssd_decode_step(cache["state"], xh[:, 0], dt[:, 0],
-                                       params["A_log"], Bh[:, 0], Ch[:, 0],
-                                       params["D"])
-        y = y[:, None]                                  # [B,1,H,P]
-    else:
-        init = cache["state"] if cache is not None else None
-        if use_kernel:
-            from repro.kernels import ops as kops
-            y, new_state = kops.ssd_scan(xh, dt, params["A_log"], Bh, Ch,
-                                         params["D"], chunk=ssm.chunk_size)
+    with jax.named_scope("scan"):
+        dt = jax.nn.softplus(dt.astype(jnp.float32)
+                             + params["dt_bias"][None, None, :])
+        if cache is not None and s == 1:
+            y, new_state = ssd_decode_step(cache["state"], xh[:, 0], dt[:, 0],
+                                           params["A_log"], Bh[:, 0], Ch[:, 0],
+                                           params["D"])
+            y = y[:, None]                              # [B,1,H,P]
         else:
-            y, new_state = ssd_chunked(xh, dt, params["A_log"], Bh, Ch,
-                                       params["D"], chunk=ssm.chunk_size,
-                                       init_state=init)
-    y = y.reshape(bsz, s, di)
-    from repro.models.layers import rms_norm
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 params["norm_scale"])
-    out = dense_(y, params["w_out"])
+            init = cache["state"] if cache is not None else None
+            if use_kernel:
+                from repro.kernels import ops as kops
+                y, new_state = kops.ssd_scan(xh, dt, params["A_log"], Bh, Ch,
+                                             params["D"], chunk=ssm.chunk_size)
+            else:
+                y, new_state = ssd_chunked(xh, dt, params["A_log"], Bh, Ch,
+                                           params["D"], chunk=ssm.chunk_size,
+                                           init_state=init)
+    with jax.named_scope("gate_norm"):
+        y = y.reshape(bsz, s, di)
+        from repro.models.layers import rms_norm
+        y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
+                     params["norm_scale"])
+    with jax.named_scope("out_proj"):
+        out = dense_(y, params["w_out"])
     new_cache = ({"conv": new_conv, "state": new_state}
                  if cache is not None else None)
     return out, new_cache

@@ -442,10 +442,13 @@ def block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
 @jax.named_scope("ssd")
 def mamba_layer_apply(cfg: ArchConfig, p: Params, x: jax.Array,
                       cache: Optional[Dict] = None, use_kernel: bool = False):
-    h = L.rms_norm(x, p["ln"], cfg.norm_eps)
+    """Pre-norm Mamba2 layer.  The residual ``x`` keeps its own dtype (float32
+    under ``cfg.residual_in_fp32``); the mixer sees the normed input in
+    ``cfg.dtype``."""
+    h = L.rms_norm(x, p["ln"], cfg.norm_eps).astype(cfg.dtype)
     out, new_cache = M.mamba_block_apply(p["mamba"], h, cfg.ssm, cache,
                                          use_kernel=use_kernel)
-    return x + out, new_cache, jnp.zeros((), jnp.float32)
+    return x + out.astype(x.dtype), new_cache, jnp.zeros((), jnp.float32)
 
 
 def _remat_wrap(fn, remat: str):
@@ -709,10 +712,17 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
 
 @jax.named_scope("ce_head")
 def _head(cfg: ArchConfig, params: Params, x: jax.Array) -> jax.Array:
-    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps).astype(cfg.dtype)
     if cfg.tie_embeddings:
         return jnp.einsum("bsd,vd->bsv", h, params["embed"]).astype(jnp.float32)
     return jnp.einsum("bsd,dv->bsv", h, params["lm_head"]).astype(jnp.float32)
+
+
+@jax.named_scope("embed")
+def _embed(cfg: ArchConfig, params: Params, tokens: jax.Array) -> jax.Array:
+    """Token embeddings: the residual stream the layer stack carries."""
+    x = jnp.take(params["embed"], tokens, axis=0)
+    return x.astype(jnp.float32) if cfg.residual_in_fp32 else x
 
 
 def run_encoder(cfg: ArchConfig, params: Params, frontend: jax.Array,
@@ -735,8 +745,7 @@ def forward_hidden(cfg: ArchConfig, params: Params, tokens: jax.Array,
                    ) -> Tuple[jax.Array, jax.Array]:
     """Trunk only: returns (pre-head hidden [B,S_total,d], aux_loss)."""
     b, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed(cfg, params, tokens)
     if cfg.enc_dec is not None:
         assert frontend is not None, "enc-dec arch needs frontend embeddings"
         enc_out = run_encoder(cfg, params, frontend, remat, use_kernel)
@@ -865,8 +874,7 @@ def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
             capacity_factor: Optional[float] = None) -> Tuple[jax.Array, Cache]:
     """Fill the decode cache from a prompt; returns (last-token logits, cache)."""
     b, s = tokens.shape
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], tokens, axis=0)
+    x = _embed(cfg, params, tokens)
     offset = 0
     if cfg.enc_dec is not None:
         assert frontend is not None
@@ -906,8 +914,7 @@ def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
     """One decoding step.  token: [B] int32.  Returns (logits [B,V], cache)."""
     b = token.shape[0]
     pos = cache["pos"]
-    with jax.named_scope("embed"):
-        x = jnp.take(params["embed"], token[:, None], axis=0)
+    x = _embed(cfg, params, token[:, None])
     positions = jnp.broadcast_to(pos[None, None], (b, 1))
     new_cache: Cache = {"pos": pos + 1}
 

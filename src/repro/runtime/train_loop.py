@@ -59,8 +59,13 @@ def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
     def train_step(params, opt_state, ef_state, batch):
         if micro > 1:
             def split(x):
+                # microbatch k takes rows k, k + micro, ...: each keeps a
+                # share of every data shard's rows, so it stays spread
+                # over the batch axes (contiguous blocks of rows would put
+                # each microbatch on one data shard and leave that axis
+                # to shard the model's width)
                 b = x.shape[0]
-                return x.reshape((micro, b // micro) + x.shape[1:])
+                return x.reshape((b // micro, micro) + x.shape[1:]).swapaxes(0, 1)
             micro_batches = jax.tree.map(split, batch)
 
             def mb_step(carry, mb):

@@ -16,6 +16,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import Mesh, SingleDeviceSharding
 
@@ -196,3 +197,63 @@ def test_serve_prefill_fits_one_chip(serve_engine, one_chip):
     tokens = _sds((SERVE_BATCH, SERVE_PROMPT), jnp.int32, one_chip)
     compiled = engine._prefill.lower(engine.params, tokens).compile()
     assert _used_bytes(compiled.memory_analysis()) <= TPU_V5E.hbm_bytes
+
+
+MAMBA2_SCOPES = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
+
+
+def _replica_groups(line):
+    """The device groups of one collective in compiled HLO text (explicit,
+    iota ``[n,k]<=[dims]T(perm)`` or permute pairs), as frozensets."""
+    m = re.search(r"replica_groups=\{((?:\{[\d,]+\},?)+)\}", line)
+    if m:
+        return {frozenset(map(int, g.split(",")))
+                for g in re.findall(r"\{([\d,]+)\}", m.group(1))}
+    m = re.search(r"replica_groups=\[(\d+),(\d+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?",
+                  line)
+    if m:
+        ids = np.arange(int(m.group(1)) * int(m.group(2))).reshape(
+            [int(d) for d in m.group(3).split(",")])
+        if m.group(4):
+            ids = ids.transpose([int(d) for d in m.group(4).split(",")])
+        return {frozenset(r) for r in ids.reshape(int(m.group(1)), -1).tolist()}
+    m = re.search(r"source_target_pairs=\{((?:\{\d+,\d+\},?)+)\}", line)
+    if m:
+        return {frozenset(map(int, p.split(",")))
+                for p in re.findall(r"\{(\d+,\d+)\}", m.group(1))}
+    return set()
+
+
+def test_mamba2_tp_step_fits_a_2x2_and_names_its_parts(topo):
+    """mamba2-1.3b as published (tied head over 50288 rows, float32
+    residual), 8 x 2048 tokens, with the planner's pick for a v5e 2x2: the
+    step fits each chip's HBM, every part of the SSD block names its device
+    operations, and each microbatch stays data-parallel, so the forward
+    collectives of the SSD block run within a tensor-parallel pair."""
+    from repro.runtime.train_loop import Trainer, TrainerConfig
+
+    arch = dataclasses.replace(get_config("mamba2-1.3b"), tie_embeddings=True,
+                               vocab_size=50288, residual_in_fp32=True)
+    shape = dataclasses.replace(SHAPES["train_4k"], global_batch=8, seq_len=2048)
+    cc = local_cluster_config("TPU v5 lite", (2, 2), ("data", "model"))
+    best = choose_plan(arch, shape, cc, top_k=1)[0]
+    assert best.feasible and best.plan.tp_axes == ("model",)
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    trainer = Trainer(arch, shape, cc, mesh, plan=best.plan,
+                      tcfg=TrainerConfig(steps=1))
+    compiled = compile_train_step(trainer)
+    assert _used_bytes(compiled.memory_analysis()) <= TPU_V5E.hbm_bytes
+    text = compiled.as_text()
+    for scope in MAMBA2_SCOPES:
+        assert f"/ssd/{scope}/" in text, scope
+    ids = np.arange(4).reshape(2, 2)
+    tp_pairs = {frozenset(r) for r in ids.tolist()}
+    forward = []
+    for line in text.splitlines():
+        op = re.search(r'op_name="([^"]*)"', line)
+        if (re.search(r" (all-reduce|all-gather|collective-permute|all-to-all|"
+                      r"reduce-scatter)(-start)?\(", line)
+                and op and "/ssd/" in op.group(1)
+                and "transpose(" not in op.group(1)):
+            forward.append(_replica_groups(line))
+    assert forward and all(g == tp_pairs for g in forward), forward
