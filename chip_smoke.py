@@ -21,8 +21,9 @@ Phases (one chip):
   * serve   — the same model through ``ServeEngine``: 8 prompts of 128
               tokens, 32 greedy new tokens each.  Checks that decode
               logits match a fresh prefill of the same prefix.
-  * kernels — ``flash_attention`` and ``ssd_scan`` compiled for the chip
-              at real widths, each against its ``kernels/ref.py`` oracle.
+  * kernels — ``flash_attention``, ``decode_attention`` and ``ssd_scan``
+              compiled for the chip at real widths, each against its
+              ``kernels/ref.py`` oracle.
 """
 from __future__ import annotations
 
@@ -141,6 +142,7 @@ def phase_serve(jax):
             f"prefill_s={c.prefill_time_s:.4f} decode_s={c.decode_time_s:.4f} "
             f"new_tokens/s={len(outs) * SERVE_NEW / wall:.1f} "
             f"{compiled_since(snap)}")
+    log(f"[serve] engine stats={engine.stats}")
     for c in outs:
         if len(c.tokens) != SERVE_NEW or not all(
                 0 <= t < arch.vocab_size for t in c.tokens):
@@ -184,6 +186,20 @@ def phase_kernels(jax):
         f"{compiled_since(snap)}")
     if not err <= 3e-2:
         raise AssertionError(f"flash_attention error {err}")
+
+    # decode attention: qwen1.5-0.5b's stacked cache, 32 lanes, 1280 slots
+    kv = jax.random.normal(key[3], (24, 1280, 16, 32, 128), jnp.bfloat16)
+    q = jax.random.normal(key[4], (32, 16, 64), jnp.bfloat16)
+    kpos = jnp.where(jnp.arange(1280) <= 1100, jnp.arange(1280), -1)
+    args = (q, kv, kpos.astype(jnp.int32), 7, 1100)
+    snap = compile_snapshot()
+    out = ops.decode_attention(*args).block_until_ready()
+    err = float(jnp.max(jnp.abs(out - ref.decode_attention_ref(*args))))
+    log(f"[kernels] decode_attention {tuple(kv.shape)} bf16 pos=1100: "
+        f"max_abs_err={err:.6f} {compiled_since(snap)}")
+    if not err <= 2e-2:
+        raise AssertionError(f"decode_attention error {err}")
+    del kv
 
     # SSD scan: mamba2-1.3b widths (64 heads x 64, state 128, chunk 256)
     b, s, h, p, n = 1, 2048, 64, 64, 128

@@ -12,6 +12,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import decode_attention as _da
 from repro.kernels import flash_attention as _fa
 from repro.kernels import matmul_epilogue as _mme
 from repro.kernels import ssd_scan as _ssd
@@ -58,6 +59,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window, scale=scale, bq=bq, bk=bk,
         interpret=_interpret())
+
+
+def decode_attention(q, kv, kpos, layer, pos, *,
+                     window: Optional[int] = None) -> jax.Array:
+    """One-token attention of q [B,Hq,hd] over layer ``layer`` of the
+    stacked slot-major cache kv [L,S,Hkv,B,2*hd], whose slots hold the
+    positions kpos [S]; float32 [B,Hq,hd]."""
+    return _da.decode_attention(q, kv, kpos, layer, pos, window=window,
+                                interpret=_interpret())
 
 
 def ssd_scan(x, dt, A_log, B, C, D, *,

@@ -44,6 +44,16 @@ def flash_attention_ref(q, k, v, *, causal: bool = True,
     return attention_dense(q, k, v, causal=causal, window=window, scale=scale)
 
 
+def decode_attention_ref(q, kv, kpos, layer, pos, *,
+                         window: Optional[int] = None) -> jax.Array:
+    """The model's dense decode product over layer ``layer`` of the stacked
+    cache (``transformer.dense_cached_attention``), float32 [B,Hq,hd]."""
+    from repro.models.transformer import dense_cached_attention
+    out = dense_cached_attention(q.astype(jnp.float32)[:, :, None], kv, kpos,
+                                 layer, pos, window)
+    return out[:, :, 0]
+
+
 def ssd_scan_ref(x, dt, A_log, B, C, D, *, chunk: int = 256,
                  ) -> Tuple[jax.Array, jax.Array]:
     """Chunked-SSD oracle (validated against the sequential recurrence)."""

@@ -57,6 +57,11 @@ class Model:
         """The decode writes its K/V rows into the stacked cache in place."""
         return T.decode_carries_cache(self.cfg)
 
+    def decode_runs_kernel(self, cache, platform: str) -> bool:
+        """A one-token decode over ``cache`` on one device of ``platform``
+        runs the decode-attention kernel."""
+        return T.decode_runs_kernel(self.cfg, cache, platform)
+
     def decode_step(self, params, token, cache, *, use_kernel: bool = False,
                     capacity_factor=None):
         return T.decode_step(self.cfg, params, token, cache,

@@ -28,8 +28,11 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import jax.extend
+from jax.interpreters import mlir
 
 from repro.configs.base import ArchConfig
+from repro.kernels import decode_attention as _da
 from repro.models import costing_mode
 from repro.models import layers as L
 from repro.models import mamba as M
@@ -249,15 +252,11 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                 kv = _put(kv, rows, at + (slot, 0, 0, 0))
                 kpos = _put(kpos, pos[None].astype(kpos.dtype), at + (slot,))
                 new_cache = {"kv": kv, "kpos": kpos}
-                if layer is not None:
-                    kpos = jax.lax.dynamic_index_in_dim(kpos, layer,
-                                                        keepdims=False)
-                valid = (kpos >= 0) & (kpos <= pos)
-                if window is not None:
-                    valid &= kpos > pos - window
-                scores_mask = valid[None, None, None, :]
-                ck, cv = (_cached_heads(kv, at, i * hd, hd) for i in (0, 1))
-                out = _masked_dense_attention(q, ck, cv, scores_mask)
+                if layer is None:
+                    kv, kpos, layer = kv[None], kpos[None], 0
+                kpos = jax.lax.dynamic_index_in_dim(kpos, layer, keepdims=False)
+                out = _cached_attention(q, kv, kpos, jnp.asarray(layer, jnp.int32),
+                                        pos, window)
             else:                                          # prefill
                 if s >= cap:
                     kv = rows[s - cap:]
@@ -281,13 +280,100 @@ def _put(buf: jax.Array, row: jax.Array, idx: Tuple) -> jax.Array:
     return jax.lax.dynamic_update_slice(buf, row, idx)
 
 
-def _cached_heads(kv: jax.Array, at: Tuple, lane: int, hd: int) -> jax.Array:
-    """K (``lane`` 0) or V (``lane`` hd) of the cache's layer ``at`` as
+def _cached_attention(q, kv, kpos, layer, pos, window: Optional[int]):
+    """One query token per lane ([B,Hq,1,hd]) over layer ``layer`` of the
+    stacked cache ``kv`` [L,S_c,Hkv,B,2*hd], whose slots hold the positions
+    ``kpos`` [S_c].  Where the Pallas kernel takes the cache, the choice
+    is left to lowering (:data:`cached_attention_p`); elsewhere
+    :func:`dense_cached_attention`."""
+    if not _da.fits(kv.shape, kv.dtype, q.shape[1] // kv.shape[2]):
+        return dense_cached_attention(q, kv, kpos, layer, pos, window)
+    return cached_attention_p.bind(q, kv, kpos, layer, pos, window=window)
+
+
+def _kernel_attention(q, kv, kpos, layer, pos, window: Optional[int]):
+    """:func:`_cached_attention` through the Pallas kernel, which reads
+    each live row once."""
+    out = _da.decode_attention(q[:, :, 0], kv, kpos, layer, pos,
+                               window=window, interpret=False)
+    return out[:, :, None].astype(q.dtype)
+
+
+def _kernel_lowers(ctx) -> bool:
+    """Whether a Mosaic kernel lowers in ``ctx``'s program: one for a
+    single device, or the body of a ``shard_map`` over every mesh axis.
+    The partitioner cannot split a Mosaic kernel, so a program it
+    partitions keeps the XLA products (read by attribute: ``num_devices``
+    on a jit's context, ``manual_axes`` on a shard_map's)."""
+    axes = ctx.module_context.axis_context
+    if hasattr(axes, "num_devices"):
+        return axes.num_devices == 1
+    if hasattr(axes, "manual_axes"):
+        manual = set(axes.manual_axes) | set(axes.mesh.manual_axes)
+        return manual >= set(axes.mesh.axis_names)
+    return True
+
+
+# The decode's attention over a cache the kernel takes, chosen where the
+# program is lowered: the kernel on a TPU wherever a Mosaic kernel lowers,
+# else the XLA products.
+cached_attention_p = jax.extend.core.Primitive("cached_attention")
+cached_attention_p.def_abstract_eval(lambda q, *_, window: q)
+cached_attention_p.def_impl(jax.jit(
+    lambda *args, window: cached_attention_p.bind(*args, window=window),
+    static_argnames="window"))
+
+
+def _lower_cached_attention(ctx, *args, window, kernel=False):
+    """The kernel where ``kernel`` (a TPU) and a Mosaic kernel lowers in
+    ``ctx``, else the XLA products."""
+    fn = _kernel_attention if kernel and _kernel_lowers(ctx) else \
+        dense_cached_attention
+    return mlir.lower_fun(fn, multiple_results=False)(ctx, *args,
+                                                      window=window)
+
+
+mlir.register_lowering(cached_attention_p, _lower_cached_attention)
+mlir.register_lowering(cached_attention_p,
+                       partial(_lower_cached_attention, kernel=True),
+                       platform="tpu")
+
+
+def decode_runs_kernel(cfg: ArchConfig, cache: Cache, platform: str) -> bool:
+    """Whether a one-token decode of ``cfg`` over ``cache``, in a program
+    for one device of ``platform``, runs the decode-attention kernel
+    (:func:`_cached_attention`'s choice)."""
+    kvs = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
+           if getattr(path[-1], "key", None) == "kv"]
+    g = cfg.n_heads // max(cfg.n_kv_heads, 1)
+    return platform == "tpu" and any(_da.fits(kv.shape, kv.dtype, g)
+                                     for kv in kvs)
+
+
+def _live_slots(kpos, pos, window: Optional[int]):
+    """Slots that hold a key the query at ``pos`` attends."""
+    valid = (kpos >= 0) & (kpos <= pos)
+    if window is not None:
+        valid &= kpos > pos - window
+    return valid
+
+
+def dense_cached_attention(q, kv, kpos, layer, pos, window: Optional[int]):
+    """:func:`_cached_attention` as XLA products: keys and values sliced
+    apart, every slot read, dead slots masked."""
+    valid = _live_slots(kpos, pos, window)
+    hd = q.shape[-1]
+    ck, cv = (_cached_heads(kv, layer, i * hd, hd) for i in (0, 1))
+    return _masked_dense_attention(q, ck, cv, valid[None, None, None, :])
+
+
+def _cached_heads(kv: jax.Array, layer, lane: int, hd: int) -> jax.Array:
+    """K (``lane`` 0) or V (``lane`` hd) of the cache's layer ``layer`` as
     [B,Hkv,S_c,hd].  K and V are sliced apart, so each slice fuses into the
     one attention product that reads it instead of being materialized."""
-    size = (1,) * len(at) + kv.shape[len(at):-1] + (hd,)
-    x = jax.lax.dynamic_slice(kv, at + (0, 0, 0, lane), size)
-    return x.reshape(x.shape[len(at):]).transpose(2, 1, 0, 3)
+    x = jax.lax.dynamic_slice(kv, (layer, 0, 0, 0, lane),
+                              (1,) + kv.shape[1:-1] + (hd,))
+    return x[0].transpose(2, 1, 0, 3)
 
 
 def _masked_dense_attention(q, k, v, mask) -> jax.Array:

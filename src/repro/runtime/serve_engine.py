@@ -21,7 +21,10 @@ instead, but this reference engine keeps the cache dense and the code
 honest about it.  The decode step is one jit-compiled executable — the
 `serve_step` the dry-run lowers at production shapes.  It takes the cache
 donated; ``stats["decode_in_place"]`` counts the steps whose decode writes
-its K/V rows where they lie (``Model.decode_carries_cache``).
+its K/V rows where they lie (``Model.decode_carries_cache``), and
+``stats["decode_attention_kernel"]`` the steps whose decode program, on
+the device the cache lives on, runs the Pallas decode-attention kernel
+(``Model.decode_runs_kernel``).
 """
 from __future__ import annotations
 
@@ -127,8 +130,10 @@ class ServeEngine:
         self._active: List[_Slot] = []
         self._cache: Any = None
         self._next_rid = 0
+        self._decode_kernel = False    # the cache's decode runs the kernel
         self.stats: Dict[str, int] = {"decode_steps": 0,
                                       "decode_in_place": 0,
+                                      "decode_attention_kernel": 0,
                                       "admission_rounds": 0,
                                       "wasted_slot_steps": 0}
 
@@ -182,6 +187,9 @@ class ServeEngine:
         t0 = time.perf_counter()
         logits, self._cache = self._prefill(self.params,
                                             jnp.asarray(prompts), frontend)
+        device, = jax.tree.leaves(self._cache)[0].devices()
+        self._decode_kernel = self.model.decode_runs_kernel(self._cache,
+                                                            device.platform)
         tok = self._sample(logits)
         with tracing.span("serve.prefill_wait"):
             tok = np.asarray(tok)
@@ -257,6 +265,7 @@ class ServeEngine:
         dt = time.perf_counter() - t0
         self.stats["decode_steps"] += 1
         self.stats["decode_in_place"] += self.model.decode_carries_cache
+        self.stats["decode_attention_kernel"] += self._decode_kernel
         self.stats["wasted_slot_steps"] += len(self._active) - len(live)
         for i, s in enumerate(self._active):
             if not s.done:

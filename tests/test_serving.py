@@ -155,7 +155,8 @@ def test_engine_decode_donates_and_writes_cache_in_place(arch_id):
     """Decode steps through the engine give forward's greedy tokens, and
     the cache handed to each decode is donated (its buffers are deleted).
     Only stacked K/V caches are written in place, and only their steps
-    count as ``decode_in_place``."""
+    count as ``decode_in_place``; no CPU step counts as running the
+    decode-attention kernel."""
     from repro.models import transformer as T
 
     cfg = _tiny(arch_id)
@@ -178,6 +179,8 @@ def test_engine_decode_donates_and_writes_cache_in_place(arch_id):
     assert steps == 5
     in_place = arch_id != "mamba2-1.3b"
     assert engine.stats["decode_in_place"] == (steps if in_place else 0)
+    # the decode-attention kernel is lowered for TPUs only
+    assert engine.stats["decode_attention_kernel"] == 0
     for rid, prompt in zip(rids, prompts):
         seq = prompt + done[rid].tokens
         logits, _ = T.forward(cfg, params, jnp.asarray([seq]),

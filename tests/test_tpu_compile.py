@@ -23,6 +23,7 @@ from jax.sharding import Mesh, SingleDeviceSharding
 from repro.configs import SHAPES, get_config
 from repro.core.cluster import TPU_V5E, local_cluster_config
 from repro.core.planner import choose_plan
+from repro.kernels import decode_attention as da
 from repro.kernels import flash_attention as fa
 from repro.kernels import matmul_epilogue as mme
 from repro.kernels import ssd_scan as ssd
@@ -67,6 +68,16 @@ def test_flash_attention_compiles(one_chip):
     x = _sds((4, 16, 2048, 64), jnp.bfloat16, one_chip)
     fn = partial(fa.flash_attention, bq=512, bk=512, interpret=False)
     _assert_kernel(jax.jit(fn).lower(x, x, x).compile())
+
+
+def test_decode_attention_compiles_at_qwen_widths(one_chip):
+    # qwen1.5-0.5b serving: 24 layers, 1280 slots, 16 kv heads of 64, 32 lanes
+    q = _sds((32, 16, 64), jnp.bfloat16, one_chip)
+    kv = _sds((24, 1280, 16, 32, 128), jnp.bfloat16, one_chip)
+    kpos = _sds((1280,), jnp.int32, one_chip)
+    i = _sds((), jnp.int32, one_chip)
+    fn = partial(da.decode_attention, interpret=False)
+    _assert_kernel(jax.jit(fn).lower(q, kv, kpos, i, i).compile())
 
 
 def test_ssd_scan_compiles_at_mamba2_widths(one_chip):
@@ -164,8 +175,10 @@ def _used_bytes(mem):
 
 def test_serve_decode_keeps_kv_cache_in_place(serve_engine, one_chip):
     """The decode writes each layer's new K/V row into the donated cache
-    where it lies: no copy of a layer's or the stack's K/V cache, the
-    cache aliased from input to output, and almost no temporaries."""
+    where it lies and reads it in the decode-attention kernel: no copy of
+    a layer's or the stack's K/V cache, the cache aliased from input to
+    output, almost no temporaries, and the engine sees the kernel in the
+    program it lowers."""
     engine = serve_engine
     assert engine.model.decode_carries_cache
     cache = jax.tree.map(
@@ -173,12 +186,15 @@ def test_serve_decode_keeps_kv_cache_in_place(serve_engine, one_chip):
         engine.model.cache_shapes(SERVE_BATCH, SERVE_MAX_LEN))
     token = _sds((SERVE_BATCH,), jnp.int32, one_chip)
     compiled = engine._decode.lower(engine.params, token, cache).compile()
+    text = compiled.as_text()
+    assert re.search(rf"%{da.NAME}[.\d]* = .* custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', text)
+    assert engine.model.decode_runs_kernel(cache, "tpu")
     # a layer's K/V [32,16,1280,64] in any axis order, or its slot-major
     # rows [1280,16,32,128], alone or stacked
     kv_dims = [sorted((SERVE_BATCH, 16, SERVE_MAX_LEN, 64)),
                sorted((SERVE_MAX_LEN, 16, SERVE_BATCH, 128))]
-    copies = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(",
-                        compiled.as_text())
+    copies = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} copy\(", text)
     assert copies, "no copy at all: the pattern no longer matches the text"
     cache_copies = [c for c in copies if c.count(",") >= 3
                     and sorted(map(int, c.split(",")[-4:])) in kv_dims]
@@ -197,6 +213,55 @@ def test_serve_prefill_fits_one_chip(serve_engine, one_chip):
     tokens = _sds((SERVE_BATCH, SERVE_PROMPT), jnp.int32, one_chip)
     compiled = engine._prefill.lower(engine.params, tokens).compile()
     assert _used_bytes(compiled.memory_analysis()) <= TPU_V5E.hbm_bytes
+
+
+def test_sharded_serve_decode_lowers_on_a_2x2(topo):
+    """qwen1.5-0.5b's decode with its cache laid out by
+    ``cache_shardings`` (lanes over data, kv heads over model) on a
+    described v5e 2x2 lowers and compiles with the XLA products, since the
+    partitioner cannot split a Mosaic kernel, and gathers no cache.  The
+    same attention inside a shard_map over the cache's axes runs the
+    decode-attention kernel on each chip's shard."""
+    from jax.sharding import PartitionSpec as P
+    from repro.core.planner import ShardingPlan
+    from repro.launch import shardings as S
+    from repro.models import transformer as T
+    from repro.models.model import build_model
+
+    model = build_model(get_config("qwen1.5-0.5b"))
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("data", "model"))
+    plan = ShardingPlan(batch_axes=("data",), tp_axes=("model",))
+    pshapes = model.init_shapes()
+    cshapes = model.cache_shapes(SERVE_BATCH, SERVE_MAX_LEN)
+
+    def placed(shapes, shardings):
+        return jax.tree.map(lambda s, sh: _sds(s.shape, s.dtype, sh),
+                            shapes, shardings)
+
+    params = placed(pshapes, S.params_shardings(mesh, plan, pshapes))
+    cache = placed(cshapes, S.cache_shardings(mesh, plan, cshapes))
+    kv = cache["self"]["kv"]
+    assert kv.sharding.spec[2:4] == ("model", "data")
+    token = _sds((SERVE_BATCH,), jnp.int32, S.batch_shardings(
+        mesh, plan, {"t": jax.ShapeDtypeStruct((SERVE_BATCH,), jnp.int32)})["t"])
+    step = jax.jit(model.decode_step, donate_argnums=(2,))
+    text = step.lower(params, token, cache).compile().as_text()
+    assert "tpu_custom_call" not in text
+    gathers = re.findall(r"= \w+\[([\d,]*)\]\{[^}]*\} all-gather", text)
+    assert not [g for g in gathers if g.count(",") >= 3], gathers
+
+    lanes_heads = P("data", "model")
+    attend = jax.shard_map(
+        lambda q, kv, kpos: T._cached_attention(q, kv, kpos, 3, 1000, None),
+        mesh=mesh, in_specs=(lanes_heads, kv.sharding.spec, P()),
+        out_specs=lanes_heads, check_vma=False)
+    q = _sds((SERVE_BATCH, 16, 1, 64), jnp.bfloat16,
+             jax.sharding.NamedSharding(mesh, lanes_heads))
+    kpos = _sds((SERVE_MAX_LEN,), jnp.int32,
+                jax.sharding.NamedSharding(mesh, P()))
+    text = jax.jit(attend).lower(q, kv, kpos).compile().as_text()
+    assert re.search(rf"%{da.NAME}[.\d]* = .* custom-call\(.*"
+                     r'custom_call_target="tpu_custom_call"', text)
 
 
 MAMBA2_SCOPES = ("in_proj", "conv", "scan", "gate_norm", "out_proj")
