@@ -14,7 +14,7 @@ import numpy as np
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
-from repro.runtime.serve_engine import Request, ServeEngine
+from repro.runtime.serve_engine import EngineConfig, Request, ServeEngine
 
 
 def main() -> None:
@@ -34,8 +34,9 @@ def main() -> None:
         arch = dataclasses.replace(arch.reduced(), dtype="float32")
     model = build_model(arch)
     params = model.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(model, params, max_len=args.max_len,
-                         temperature=args.temperature)
+    engine = ServeEngine(model, params,
+                         EngineConfig(max_len=args.max_len,
+                                      temperature=args.temperature))
 
     rng = np.random.default_rng(0)
     reqs = [Request(prompt=list(rng.integers(1, arch.vocab_size,

@@ -204,18 +204,9 @@ def attention_chunked(q, k, v, *, causal: bool = True,
 
 
 def attention(q, k, v, *, causal: bool = True, window: Optional[int] = None,
-              q_offset=0, scale: Optional[float] = None,
-              use_kernel: bool = False) -> jax.Array:
-    """Dispatch: dense for small/decode, chunked for long prefill/train.
-
-    ``use_kernel=True`` routes to the Pallas flash kernel (TPU target;
-    interpret-mode on CPU) — see repro.kernels.ops.
-    """
+              q_offset=0, scale: Optional[float] = None) -> jax.Array:
+    """Dispatch: dense for small/decode, chunked for long prefill/train."""
     sq, skv = q.shape[2], k.shape[2]
-    if use_kernel and sq > 1 and q.shape[-1] == v.shape[-1]:
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, causal=causal, window=window,
-                                    scale=scale)
     if sq == 1 or (sq <= 2048 and skv <= 2048):
         return attention_dense(q, k, v, causal=causal, window=window,
                                q_offset=q_offset, scale=scale)

@@ -155,7 +155,6 @@ def _causal_conv(xc: jax.Array, w: jax.Array, b: jax.Array,
 
 def mamba_block_apply(params: Dict[str, jax.Array], x: jax.Array, ssm,
                       cache: Optional[Dict[str, jax.Array]] = None,
-                      use_kernel: bool = False,
                       ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """x: [B, S, d_model].  cache: {"conv": [B,W-1,C], "state": [B,H,P,N]}."""
     bsz, s, d = x.shape
@@ -187,14 +186,9 @@ def mamba_block_apply(params: Dict[str, jax.Array], x: jax.Array, ssm,
             y = y[:, None]                              # [B,1,H,P]
         else:
             init = cache["state"] if cache is not None else None
-            if use_kernel:
-                from repro.kernels import ops as kops
-                y, new_state = kops.ssd_scan(xh, dt, params["A_log"], Bh, Ch,
-                                             params["D"], chunk=ssm.chunk_size)
-            else:
-                y, new_state = ssd_chunked(xh, dt, params["A_log"], Bh, Ch,
-                                           params["D"], chunk=ssm.chunk_size,
-                                           init_state=init)
+            y, new_state = ssd_chunked(xh, dt, params["A_log"], Bh, Ch,
+                                       params["D"], chunk=ssm.chunk_size,
+                                       init_state=init)
     with jax.named_scope("gate_norm"):
         y = y.reshape(bsz, s, di)
         from repro.models.layers import rms_norm

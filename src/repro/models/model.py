@@ -30,15 +30,12 @@ class Model:
 
     # ------------------------------------------------------------ training
     def loss(self, params, batch, *, remat: str = "none",
-             use_kernel: bool = False, capacity_factor=None):
+             capacity_factor=None):
         return T.loss_fn(self.cfg, params, batch, remat=remat,
-                         use_kernel=use_kernel,
                          capacity_factor=capacity_factor)
 
-    def forward(self, params, tokens, frontend=None, *, remat="none",
-                use_kernel: bool = False):
-        return T.forward(self.cfg, params, tokens, frontend, remat=remat,
-                         use_kernel=use_kernel)
+    def forward(self, params, tokens, frontend=None, *, remat="none"):
+        return T.forward(self.cfg, params, tokens, frontend, remat=remat)
 
     # ------------------------------------------------------------ serving
     def init_cache(self, batch: int, max_len: int) -> T.Cache:
@@ -48,24 +45,12 @@ class Model:
         return jax.eval_shape(partial(T.init_cache, self.cfg, batch, max_len))
 
     def prefill(self, params, tokens, cache, frontend=None, *,
-                use_kernel: bool = False, capacity_factor=None):
+                capacity_factor=None):
         return T.prefill(self.cfg, params, tokens, cache, frontend,
-                         use_kernel=use_kernel, capacity_factor=capacity_factor)
+                         capacity_factor=capacity_factor)
 
-    @property
-    def decode_carries_cache(self) -> bool:
-        """The decode writes its K/V rows into the stacked cache in place."""
-        return T.decode_carries_cache(self.cfg)
-
-    def decode_runs_kernel(self, cache, platform: str) -> bool:
-        """A one-token decode over ``cache`` on one device of ``platform``
-        runs the decode-attention kernel."""
-        return T.decode_runs_kernel(self.cfg, cache, platform)
-
-    def decode_step(self, params, token, cache, *, use_kernel: bool = False,
-                    capacity_factor=None):
+    def decode_step(self, params, token, cache, *, capacity_factor=None):
         return T.decode_step(self.cfg, params, token, cache,
-                             use_kernel=use_kernel,
                              capacity_factor=capacity_factor)
 
     # ------------------------------------------------------------- helpers

@@ -211,7 +211,6 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                   causal: bool = True,
                   kv_cache: Optional[Dict[str, jax.Array]] = None,
                   kv_source: Optional[jax.Array] = None,
-                  use_kernel: bool = False,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """Standard GQA attention.  x: [B,S,d].
 
@@ -234,8 +233,7 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
         q = L.apply_rope(q, positions[:, None, :].repeat(nh, 1), cfg.rope_theta)
         k = L.apply_rope(k, positions[:, None, :].repeat(nkv, 1), cfg.rope_theta)
         if kv_cache is None:
-            out = L.attention(q, k, v, causal=causal, window=window,
-                              use_kernel=use_kernel)
+            out = L.attention(q, k, v, causal=causal, window=window)
         else:
             kv, kpos = kv_cache["kv"], kv_cache["kpos"]
             # "layer": the whole stacked cache rides the layers
@@ -266,8 +264,7 @@ def gqa_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                                                              axis=0)
                     kpos = jax.lax.dynamic_update_slice_in_dim(
                         kpos, positions[0].astype(jnp.int32), 0, axis=0)
-                out = L.attention(q, k, v, causal=causal, window=window,
-                                  use_kernel=use_kernel)
+                out = L.attention(q, k, v, causal=causal, window=window)
                 new_cache = {"kv": kv, "kpos": kpos}
     out = out.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
     return L.dense(out, p["w_o"]), new_cache
@@ -339,17 +336,6 @@ mlir.register_lowering(cached_attention_p,
                        platform="tpu")
 
 
-def decode_runs_kernel(cfg: ArchConfig, cache: Cache, platform: str) -> bool:
-    """Whether a one-token decode of ``cfg`` over ``cache``, in a program
-    for one device of ``platform``, runs the decode-attention kernel
-    (:func:`_cached_attention`'s choice)."""
-    kvs = [leaf for path, leaf in jax.tree_util.tree_leaves_with_path(cache)
-           if getattr(path[-1], "key", None) == "kv"]
-    g = cfg.n_heads // max(cfg.n_kv_heads, 1)
-    return platform == "tpu" and any(_da.fits(kv.shape, kv.dtype, g)
-                                     for kv in kvs)
-
-
 def _live_slots(kpos, pos, window: Optional[int]):
     """Slots that hold a key the query at ``pos`` attends."""
     valid = (kpos >= 0) & (kpos <= pos)
@@ -396,7 +382,6 @@ def _masked_dense_attention(q, k, v, mask) -> jax.Array:
 def mla_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
                   positions: jax.Array,
                   kv_cache: Optional[Dict[str, jax.Array]] = None,
-                  use_kernel: bool = False,
                   ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """DeepSeek MLA.  Cache holds the compressed latent (c_kv + k_rope)."""
     m = cfg.mla
@@ -448,7 +433,7 @@ def mla_attention(cfg: ArchConfig, p: Params, x: jax.Array, *,
         k = jnp.concatenate([k_nope, jnp.broadcast_to(
             k_rope, (b, nh, s, rd)).astype(k_nope.dtype)], axis=-1)
         qf = jnp.concatenate([q_nope, q_rope], axis=-1)
-        o = L.attention(qf, k, v, causal=True, scale=scale, use_kernel=use_kernel)
+        o = L.attention(qf, k, v, causal=True, scale=scale)
         out = o.transpose(0, 2, 1, 3).reshape(b, s, nh * dv_)
         if kv_cache is not None:                           # prefill fills cache
             cc = jax.lax.dynamic_update_slice_in_dim(
@@ -488,21 +473,18 @@ def block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
                 causal: bool = True, moe: bool = False,
                 kv_cache: Optional[Dict] = None,
                 cross_state: Optional[Tuple] = None,
-                capacity_factor: Optional[float] = None,
-                use_kernel: bool = False):
+                capacity_factor: Optional[float] = None):
     """One transformer block. Returns (x, new_cache, aux_loss)."""
     b, s, d = x.shape
     h_in = L.rms_norm(x, p["ln1"], cfg.norm_eps)
     if cfg.mla is not None:
         attn_out, new_cache = mla_attention(cfg, p["attn"], h_in,
                                             positions=positions,
-                                            kv_cache=kv_cache,
-                                            use_kernel=use_kernel)
+                                            kv_cache=kv_cache)
     else:
         attn_out, new_cache = gqa_attention(cfg, p["attn"], h_in,
                                             positions=positions, window=window,
-                                            causal=causal, kv_cache=kv_cache,
-                                            use_kernel=use_kernel)
+                                            causal=causal, kv_cache=kv_cache)
     x = x + attn_out
     if cross_state is not None:
         ck, cv = cross_state
@@ -527,13 +509,12 @@ def block_apply(cfg: ArchConfig, p: Params, x: jax.Array, *,
 
 @jax.named_scope("ssd")
 def mamba_layer_apply(cfg: ArchConfig, p: Params, x: jax.Array,
-                      cache: Optional[Dict] = None, use_kernel: bool = False):
+                      cache: Optional[Dict] = None):
     """Pre-norm Mamba2 layer.  The residual ``x`` keeps its own dtype (float32
     under ``cfg.residual_in_fp32``); the mixer sees the normed input in
     ``cfg.dtype``."""
     h = L.rms_norm(x, p["ln"], cfg.norm_eps).astype(cfg.dtype)
-    out, new_cache = M.mamba_block_apply(p["mamba"], h, cfg.ssm, cache,
-                                         use_kernel=use_kernel)
+    out, new_cache = M.mamba_block_apply(p["mamba"], h, cfg.ssm, cache)
     return x + out.astype(x.dtype), new_cache, jnp.zeros((), jnp.float32)
 
 
@@ -572,13 +553,6 @@ def scan_stack(stacked: Params, x: jax.Array, body_fn, cache=None,
 
     x, (cache2, auxs) = jax.lax.scan(body, x, (stacked, cache))
     return x, cache2, auxs.sum()
-
-
-def decode_carries_cache(cfg: ArchConfig) -> bool:
-    """Whether a one-token decode of ``cfg`` writes K/V rows into stacked
-    caches in place (:func:`_at_layer`): every family with attention
-    caches; MLA keeps latents and the SSM its state."""
-    return cfg.family != "ssm" and cfg.mla is None
 
 
 def _at_layer(cache, i):
@@ -678,7 +652,7 @@ def init_cache(cfg: ArchConfig, batch: int, max_len: int) -> Cache:
 
 def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
                   positions: jax.Array, cache: Optional[Cache],
-                  remat: str, use_kernel: bool, capacity_factor=None):
+                  remat: str, capacity_factor=None):
     """Run the arch-specific layer stack. Returns (x, new_cache, aux)."""
     fam = cfg.family
     aux_total = jnp.zeros((), jnp.float32)
@@ -686,7 +660,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
 
     if fam == "ssm":
         def body(p, h, c):
-            return mamba_layer_apply(cfg, p, h, c, use_kernel)
+            return mamba_layer_apply(cfg, p, h, c)
         x, c2, aux = scan_stack(params["blocks"], x, body,
                                 cache["mamba"] if cache else None, remat)
         if cache is not None:
@@ -705,7 +679,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
         in_place = cache is not None and x.shape[1] == 1
 
         def body(p, h, c):
-            return mamba_layer_apply(cfg, p, h, c, use_kernel)
+            return mamba_layer_apply(cfg, p, h, c)
         for seg in range(n_seg):
             seg_params = jax.tree.map(lambda a: a[seg], mamba_stack)
             seg_cache = (jax.tree.map(lambda a: a[seg * every:(seg + 1) * every],
@@ -718,7 +692,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
             blk = _remat_wrap(
                 lambda h_, ac_, _sh=shared: block_apply(
                     cfg, _sh, h_, positions=positions, window=None,
-                    kv_cache=ac_, use_kernel=use_kernel)[:2],
+                    kv_cache=ac_)[:2],
                 remat if cache is None else "none")
             if cache is None:
                 x, _ = blk(x, None)
@@ -747,8 +721,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
             def body(p, h, c, _moe=moe_flag):
                 return block_apply(cfg, p, h, positions=positions, window=None,
                                    moe=_moe, kv_cache=c,
-                                   capacity_factor=capacity_factor,
-                                   use_kernel=use_kernel)
+                                   capacity_factor=capacity_factor)
             x, c2, aux = scan_stack(params[pname], x, body,
                                     cache[key] if cache else None, remat)
             aux_total += aux
@@ -767,8 +740,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
                 p_i = [jax.tree.map(lambda a: a, cp) for cp in [cyc_params]][0][i]
                 c_i = cyc_caches[i] if cyc_caches is not None else None
                 h, c2, a = block_apply(cfg, p_i, h, positions=positions,
-                                       window=w, kv_cache=c_i,
-                                       use_kernel=use_kernel)
+                                       window=w, kv_cache=c_i)
                 aux += a
                 new_c.append(c2 if c2 is not None else 0)
             return h, (tuple(new_c) if cyc_caches is not None else None, aux)
@@ -787,7 +759,7 @@ def _stack_runner(cfg: ArchConfig, params: Params, x: jax.Array,
     else:
         def body(p, h, c):
             return block_apply(cfg, p, h, positions=positions, window=None,
-                               kv_cache=c, use_kernel=use_kernel)
+                               kv_cache=c)
         x, c2, aux = scan_stack(params["blocks"], x, body,
                                 cache["self"] if cache else None, remat)
         aux_total += aux
@@ -812,21 +784,21 @@ def _embed(cfg: ArchConfig, params: Params, tokens: jax.Array) -> jax.Array:
 
 
 def run_encoder(cfg: ArchConfig, params: Params, frontend: jax.Array,
-                remat: str = "none", use_kernel: bool = False) -> jax.Array:
+                remat: str = "none") -> jax.Array:
     """Whisper encoder over precomputed frame embeddings [B,F,d]."""
     b, f, _ = frontend.shape
     positions = jnp.broadcast_to(jnp.arange(f), (b, f))
 
     def body(p, h, c):
         return block_apply(cfg, p, h, positions=positions, window=None,
-                           causal=False, use_kernel=use_kernel)
+                           causal=False)
     x, _, _ = scan_stack(params["enc_blocks"], frontend, body, None, remat)
     return L.rms_norm(x, params["enc_norm"], cfg.norm_eps)
 
 
 def forward_hidden(cfg: ArchConfig, params: Params, tokens: jax.Array,
                    frontend: Optional[jax.Array] = None, *,
-                   remat: str = "none", use_kernel: bool = False,
+                   remat: str = "none",
                    capacity_factor: Optional[float] = None
                    ) -> Tuple[jax.Array, jax.Array]:
     """Trunk only: returns (pre-head hidden [B,S_total,d], aux_loss)."""
@@ -834,7 +806,7 @@ def forward_hidden(cfg: ArchConfig, params: Params, tokens: jax.Array,
     x = _embed(cfg, params, tokens)
     if cfg.enc_dec is not None:
         assert frontend is not None, "enc-dec arch needs frontend embeddings"
-        enc_out = run_encoder(cfg, params, frontend, remat, use_kernel)
+        enc_out = run_encoder(cfg, params, frontend, remat)
     elif cfg.frontend != "none" and frontend is not None:
         x = jnp.concatenate([frontend.astype(x.dtype), x], axis=1)
     stot = x.shape[1]
@@ -844,23 +816,20 @@ def forward_hidden(cfg: ArchConfig, params: Params, tokens: jax.Array,
         def body(p, h, c):
             ck, cv = cross_kv(cfg, p["cross"], enc_out)
             return block_apply(cfg, p, h, positions=positions, window=None,
-                               kv_cache=c, cross_state=(ck, cv),
-                               use_kernel=use_kernel)
+                               kv_cache=c, cross_state=(ck, cv))
         x, _, aux = scan_stack(params["blocks"], x, body, None, remat)
     else:
         x, _, aux = _stack_runner(cfg, params, x, positions, None, remat,
-                                  use_kernel, capacity_factor)
+                                  capacity_factor)
     return x, aux
 
 
 def forward(cfg: ArchConfig, params: Params, tokens: jax.Array,
             frontend: Optional[jax.Array] = None, *, remat: str = "none",
-            use_kernel: bool = False,
             capacity_factor: Optional[float] = None
             ) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence forward.  Returns (logits [B,S_total,V], aux_loss)."""
     x, aux = forward_hidden(cfg, params, tokens, frontend, remat=remat,
-                            use_kernel=use_kernel,
                             capacity_factor=capacity_factor)
     logits = _head(cfg, params, x)
     return logits, aux
@@ -881,7 +850,7 @@ def mtp_hidden(cfg: ArchConfig, params: Params, h_main: jax.Array,
 
 
 def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array], *,
-            remat: str = "none", use_kernel: bool = False,
+            remat: str = "none",
             aux_weight: float = 0.01, mtp_weight: float = 0.1,
             capacity_factor: Optional[float] = None,
             ce_chunk: int = 2048):
@@ -896,7 +865,6 @@ def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array], *,
     tokens = batch["tokens"]
     frontend = batch.get("frontend")
     hidden, aux = forward_hidden(cfg, params, tokens, frontend, remat=remat,
-                                 use_kernel=use_kernel,
                                  capacity_factor=capacity_factor)
     offset = 0
     if cfg.frontend != "none" and cfg.enc_dec is None and frontend is not None:
@@ -956,7 +924,6 @@ def _chunked_ce(cfg: ArchConfig, params: Params, h: jax.Array,
 
 def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
             cache: Cache, frontend: Optional[jax.Array] = None, *,
-            use_kernel: bool = False,
             capacity_factor: Optional[float] = None) -> Tuple[jax.Array, Cache]:
     """Fill the decode cache from a prompt; returns (last-token logits, cache)."""
     b, s = tokens.shape
@@ -964,7 +931,7 @@ def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
     offset = 0
     if cfg.enc_dec is not None:
         assert frontend is not None
-        enc_out = run_encoder(cfg, params, frontend, "none", use_kernel)
+        enc_out = run_encoder(cfg, params, frontend, "none")
     elif cfg.frontend != "none" and frontend is not None:
         x = jnp.concatenate([frontend.astype(x.dtype), x], axis=1)
         offset = frontend.shape[1]
@@ -978,8 +945,7 @@ def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
             p, c = pc
             ck, cv = cross_kv(cfg, p["cross"], enc_out)
             h2, c2, _ = block_apply(cfg, p, h, positions=positions, window=None,
-                                    kv_cache=c, cross_state=(ck, cv),
-                                    use_kernel=use_kernel)
+                                    kv_cache=c, cross_state=(ck, cv))
             return h2, (c2, ck, cv)
         x, (self_c, cks, cvs) = jax.lax.scan(
             body, x, (params["blocks"], cache["self"]))
@@ -987,14 +953,14 @@ def prefill(cfg: ArchConfig, params: Params, tokens: jax.Array,
         new_cache["cross_k"], new_cache["cross_v"] = cks, cvs
     else:
         x, c2, _ = _stack_runner(cfg, params, x, positions, cache, "none",
-                                 use_kernel, capacity_factor)
+                                 capacity_factor)
         new_cache.update(c2)
     logits = _head(cfg, params, x[:, -1:])
     return logits[:, 0], new_cache
 
 
 def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
-                cache: Cache, *, use_kernel: bool = False,
+                cache: Cache, *,
                 capacity_factor: Optional[float] = None
                 ) -> Tuple[jax.Array, Cache]:
     """One decoding step.  token: [B] int32.  Returns (logits [B,V], cache)."""
@@ -1008,8 +974,7 @@ def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
         def body(pc, h, c):
             p, ck, cv = pc
             return block_apply(cfg, p, h, positions=positions, window=None,
-                               kv_cache=c, cross_state=(ck, cv),
-                               use_kernel=use_kernel)
+                               kv_cache=c, cross_state=(ck, cv))
         x, self_c, _ = scan_stack(
             (params["blocks"], cache["cross_k"], cache["cross_v"]), x, body,
             cache["self"])
@@ -1017,7 +982,7 @@ def decode_step(cfg: ArchConfig, params: Params, token: jax.Array,
         new_cache["cross_k"], new_cache["cross_v"] = cache["cross_k"], cache["cross_v"]
     else:
         x, c2, _ = _stack_runner(cfg, params, x, positions, cache, "none",
-                                 use_kernel, capacity_factor)
+                                 capacity_factor)
         new_cache.update(c2)
     logits = _head(cfg, params, x)
     return logits[:, 0], new_cache

@@ -20,11 +20,8 @@ continuation exact); a production engine would scatter the live KV rows
 instead, but this reference engine keeps the cache dense and the code
 honest about it.  The decode step is one jit-compiled executable — the
 `serve_step` the dry-run lowers at production shapes.  It takes the cache
-donated; ``stats["decode_in_place"]`` counts the steps whose decode writes
-its K/V rows where they lie (``Model.decode_carries_cache``), and
-``stats["decode_attention_kernel"]`` the steps whose decode program, on
-the device the cache lives on, runs the Pallas decode-attention kernel
-(``Model.decode_runs_kernel``).
+donated; how it writes its K/V rows and which attention it runs are the
+model's choices, made where the program is traced and lowered.
 """
 from __future__ import annotations
 
@@ -95,19 +92,10 @@ class _Slot:
 
 class ServeEngine:
     def __init__(self, model: Model, params: Any,
-                 config: Optional[EngineConfig] = None, *,
-                 max_len: int = 256, temperature: float = 0.0,
-                 seed: int = 0, capacity_factor: Optional[float] = None):
-        if config is None:
-            config = EngineConfig(max_len=max_len, temperature=temperature,
-                                  seed=seed, capacity_factor=capacity_factor)
+                 config: EngineConfig = EngineConfig()):
         self.model = model
         self.params = params
         self.config = config
-        # Legacy attribute surface (pre-EngineConfig callers read these).
-        self.max_len = config.max_len
-        self.temperature = config.temperature
-        self.capacity_factor = config.capacity_factor
         self._rng = jax.random.PRNGKey(config.seed)
         cf = config.capacity_factor
 
@@ -130,10 +118,7 @@ class ServeEngine:
         self._active: List[_Slot] = []
         self._cache: Any = None
         self._next_rid = 0
-        self._decode_kernel = False    # the cache's decode runs the kernel
         self.stats: Dict[str, int] = {"decode_steps": 0,
-                                      "decode_in_place": 0,
-                                      "decode_attention_kernel": 0,
                                       "admission_rounds": 0,
                                       "wasted_slot_steps": 0}
 
@@ -187,9 +172,6 @@ class ServeEngine:
         t0 = time.perf_counter()
         logits, self._cache = self._prefill(self.params,
                                             jnp.asarray(prompts), frontend)
-        device, = jax.tree.leaves(self._cache)[0].devices()
-        self._decode_kernel = self.model.decode_runs_kernel(self._cache,
-                                                            device.platform)
         tok = self._sample(logits)
         with tracing.span("serve.prefill_wait"):
             tok = np.asarray(tok)
@@ -264,8 +246,6 @@ class ServeEngine:
             nxt = np.asarray(nxt)
         dt = time.perf_counter() - t0
         self.stats["decode_steps"] += 1
-        self.stats["decode_in_place"] += self.model.decode_carries_cache
-        self.stats["decode_attention_kernel"] += self._decode_kernel
         self.stats["wasted_slot_steps"] += len(self._active) - len(live)
         for i, s in enumerate(self._active):
             if not s.done:

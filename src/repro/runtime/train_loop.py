@@ -6,11 +6,11 @@ compression, AdamW — all inside ONE jit so XLA/GSPMD generates a single
 runtime plan that ``hlo_cost`` can cost (the paper's object of study).
 
 ``Trainer`` adds the operational shell: cost-based plan selection,
-sharded data pipeline, async checkpointing + resume, straggler monitoring,
-and elastic re-mesh on cluster-size change.
+sharded data pipeline, async checkpointing + resume and straggler
+monitoring.
 
-``OnlineRecalibrator`` closes the estimate↔reality loop at runtime: it
-watches the measured/estimated step-time ratio (EWMA), refits a
+``OnlineRecalibrator`` closes the estimate↔reality loop for a caller that
+feeds it measured step times: it watches the measured/estimated step-time ratio (EWMA), refits a
 :class:`repro.core.calibration.CalibrationProfile` when the drift leaves
 a band, and — only when the *re-costed plan ranking changes* — routes
 through :func:`repro.runtime.elastic.replan` to switch plans.
@@ -39,18 +39,17 @@ from repro.data.pipeline import make_pipeline
 from repro.models.model import Model, build_model
 from repro.optim import adamw, compress
 from repro.runtime import tracing
-from repro.runtime.straggler import StepTimeMonitor, decide_remesh
+from repro.runtime.straggler import StepTimeMonitor
 
 
 def make_train_step(model: Model, opt_cfg: adamw.AdamWConfig,
-                    plan: ShardingPlan, *, compress_scheme: str = "none",
-                    use_kernel: bool = False) -> Callable:
+                    plan: ShardingPlan, *, compress_scheme: str = "none"
+                    ) -> Callable:
     """Returns train_step(params, opt_state, ef_state, batch) ->
     (params, opt_state, ef_state, metrics)."""
 
     def loss_of(params, batch):
-        loss, metrics = model.loss(params, batch, remat=plan.remat,
-                                   use_kernel=use_kernel)
+        loss, metrics = model.loss(params, batch, remat=plan.remat)
         return loss, metrics
 
     grad_fn = jax.value_and_grad(loss_of, has_aux=True)
@@ -251,12 +250,7 @@ class TrainerConfig:
     ckpt_dir: Optional[str] = None
     seed: int = 0
     compress_scheme: str = "none"
-    use_kernel: bool = False
     donate: bool = True
-    # Enable the online estimate↔reality loop: an OnlineRecalibrator
-    # watches measured step times and refits the calibration profile when
-    # drift leaves its band (see OnlineRecalibrator for the replan rule).
-    recalibrate: bool = False
 
 
 class Trainer:
@@ -292,8 +286,7 @@ class Trainer:
                                             opt_shapes)
 
         step_fn = make_train_step(self.model, self.opt_cfg, plan,
-                                  compress_scheme=self.tcfg.compress_scheme,
-                                  use_kernel=self.tcfg.use_kernel)
+                                  compress_scheme=self.tcfg.compress_scheme)
         # the error-feedback state: per-parameter residuals for int8_ef,
         # otherwise one replicated placeholder scalar per parameter
         rep = NamedSharding(mesh, PartitionSpec())
@@ -307,9 +300,6 @@ class Trainer:
             step_fn, donate_argnums=donate,
             out_shardings=(self.param_sh, self.opt_sh, self.ef_sh, rep))
         self.monitor = StepTimeMonitor()
-        self.recalibrator = (OnlineRecalibrator(arch, shape, cc,
-                                                plan=self.plan)
-                             if self.tcfg.recalibrate else None)
         self.checkpointer = (store.AsyncCheckpointer(self.tcfg.ckpt_dir)
                              if self.tcfg.ckpt_dir else None)
 
@@ -378,12 +368,6 @@ class Trainer:
                         dt = time.perf_counter() - t0
                         with tracing.span("train.observe"):
                             self.monitor.record({0: dt})
-                            if self.recalibrator is not None:
-                                # observation only: acting on a replan
-                                # (restore under new shardings) stays with
-                                # the caller, who reads .events / the
-                                # returned history
-                                self.recalibrator.observe(dt, step=gstep)
                             if gstep % self.tcfg.log_every == 0:
                                 history.append({"step": gstep, "time_s": dt,
                                                 **metrics})

@@ -64,11 +64,10 @@ def test_float32_residual_is_added_in_float32():
     assert float(jnp.max(jnp.abs(want - bf16))) > 100 * float(jnp.max(jnp.abs(got - want)))
 
 
-def _todays_layer(cfg, p, x, cache=None, use_kernel=False):
+def _todays_layer(cfg, p, x, cache=None):
     """``mamba_layer_apply`` as it was before the float32 residual."""
     h = L.rms_norm(x, p["ln"], cfg.norm_eps)
-    out, new_cache = M.mamba_block_apply(p["mamba"], h, cfg.ssm, cache,
-                                         use_kernel=use_kernel)
+    out, new_cache = M.mamba_block_apply(p["mamba"], h, cfg.ssm, cache)
     return x + out, new_cache, jnp.zeros((), jnp.float32)
 
 

@@ -62,7 +62,7 @@ def test_engine_greedy_deterministic():
     cfg = _tiny("qwen1.5-0.5b")
     model = build_model(cfg)
     params = model.init(RNG)
-    engine = ServeEngine(model, params, max_len=64)
+    engine = ServeEngine(model, params, EngineConfig(max_len=64))
     reqs = [Request(prompt=[5, 6, 7, 8], max_new_tokens=8),
             Request(prompt=[9, 10, 11], max_new_tokens=8)]
     out1 = engine.generate(reqs)
@@ -75,7 +75,7 @@ def test_engine_eos_stops_early():
     cfg = _tiny("qwen1.5-0.5b")
     model = build_model(cfg)
     params = model.init(RNG)
-    engine = ServeEngine(model, params, max_len=64)
+    engine = ServeEngine(model, params, EngineConfig(max_len=64))
     base = engine.generate([Request(prompt=[3, 4, 5], max_new_tokens=8)])[0]
     eos = base.tokens[2]
     out = engine.generate([Request(prompt=[3, 4, 5], max_new_tokens=8,
@@ -98,10 +98,7 @@ def test_engine_config_and_continuous_batching():
     reqs = [Request(prompt=[5, 6, 7, 8], max_new_tokens=4),
             Request(prompt=[9, 10, 11], max_new_tokens=4),
             Request(prompt=[3, 4, 5], max_new_tokens=4)]
-    # legacy kwargs == explicit config
-    static = ServeEngine(model, params, max_len=64).generate(reqs)
-    cfgd = ServeEngine(model, params, EngineConfig(max_len=64)).generate(reqs)
-    assert [c.tokens for c in cfgd] == [c.tokens for c in static]
+    static = ServeEngine(model, params, EngineConfig(max_len=64)).generate(reqs)
     # degenerate continuous schedule: slots cover the batch
     wide = ServeEngine(model, params,
                        EngineConfig(max_len=64, batching="continuous",
@@ -132,11 +129,11 @@ def test_engine_masks_finished_slots_and_reports_per_request_decode():
     cfg = _tiny("qwen1.5-0.5b")
     model = build_model(cfg)
     params = model.init(RNG)
-    engine = ServeEngine(model, params, max_len=64)
+    engine = ServeEngine(model, params, EngineConfig(max_len=64))
     base = engine.generate([Request(prompt=[5, 6, 7, 8], max_new_tokens=8),
                             Request(prompt=[9, 10, 11], max_new_tokens=8)])
     eos = base[0].tokens[1]
-    engine2 = ServeEngine(model, params, max_len=64)
+    engine2 = ServeEngine(model, params, EngineConfig(max_len=64))
     out = engine2.generate(
         [Request(prompt=[5, 6, 7, 8], max_new_tokens=8, eos_id=int(eos)),
          Request(prompt=[9, 10, 11], max_new_tokens=8)])
@@ -153,10 +150,7 @@ def test_engine_masks_finished_slots_and_reports_per_request_decode():
 ])
 def test_engine_decode_donates_and_writes_cache_in_place(arch_id):
     """Decode steps through the engine give forward's greedy tokens, and
-    the cache handed to each decode is donated (its buffers are deleted).
-    Only stacked K/V caches are written in place, and only their steps
-    count as ``decode_in_place``; no CPU step counts as running the
-    decode-attention kernel."""
+    the cache handed to each decode is donated (its buffers are deleted)."""
     from repro.models import transformer as T
 
     cfg = _tiny(arch_id)
@@ -177,10 +171,6 @@ def test_engine_decode_donates_and_writes_cache_in_place(arch_id):
     assert donated and all(donated)
     steps = engine.stats["decode_steps"]
     assert steps == 5
-    in_place = arch_id != "mamba2-1.3b"
-    assert engine.stats["decode_in_place"] == (steps if in_place else 0)
-    # the decode-attention kernel is lowered for TPUs only
-    assert engine.stats["decode_attention_kernel"] == 0
     for rid, prompt in zip(rids, prompts):
         seq = prompt + done[rid].tokens
         logits, _ = T.forward(cfg, params, jnp.asarray([seq]),
@@ -204,15 +194,13 @@ def _value_shapes(jaxpr):
     "qwen1.5-0.5b", "phi3.5-moe-42b-a6.6b", "deepseek-v3-671b", "gemma3-12b",
     "mamba2-1.3b", "zamba2-2.7b", "whisper-small"])
 def test_decode_writes_every_kv_stack_in_place(arch_id):
-    """``Model.decode_carries_cache`` holds for every family with stacked
-    ``"kv"`` caches, and the traced decode never holds one layer's slice
-    of them: each layer reads and writes the whole stack in place."""
+    """The traced decode never holds one layer's slice of a stacked
+    ``"kv"`` cache: each layer reads and writes the whole stack in place."""
     model = build_model(_tiny(arch_id))
     cache = model.cache_shapes(2, 32)
     kv = [leaf.shape for path, leaf in
           jax.tree_util.tree_flatten_with_path(cache)[0]
           if getattr(path[-1], "key", None) == "kv"]
-    assert bool(kv) == model.decode_carries_cache
     jaxpr = jax.make_jaxpr(model.decode_step)(
         model.init_shapes(), jax.ShapeDtypeStruct((2,), jnp.int32), cache)
     slices = {sh[1:] for sh in kv} | {(1,) + sh[1:] for sh in kv}

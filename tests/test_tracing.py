@@ -135,11 +135,11 @@ def test_trainer_spans_in_the_profile_match_the_record(traced_training):
 @pytest.mark.slow
 def test_serve_engine_step_spans(tmp_path):
     from repro.models.model import build_model
-    from repro.runtime.serve_engine import Request, ServeEngine
+    from repro.runtime.serve_engine import EngineConfig, Request, ServeEngine
 
     model = build_model(_tiny("qwen1.5-0.5b"))
     params = model.init(jax.random.PRNGKey(0))
-    engine = ServeEngine(model, params, max_len=32)
+    engine = ServeEngine(model, params, EngineConfig(max_len=32))
     reqs = [Request(prompt=[1, 2, 3, 4], max_new_tokens=3),
             Request(prompt=[5, 6], max_new_tokens=2)]
     with jax.profiler.trace(str(tmp_path)):
@@ -167,10 +167,11 @@ def test_serve_engine_step_spans(tmp_path):
 @pytest.mark.slow
 def test_serving_programs_have_stable_names():
     from repro.models.model import build_model
-    from repro.runtime.serve_engine import Request, ServeEngine
+    from repro.runtime.serve_engine import EngineConfig, Request, ServeEngine
 
     model = build_model(_tiny("qwen1.5-0.5b"))
-    engine = ServeEngine(model, model.init(jax.random.PRNGKey(1)), max_len=16)
+    engine = ServeEngine(model, model.init(jax.random.PRNGKey(1)),
+                         EngineConfig(max_len=16))
     t0 = time.perf_counter()
     engine.generate([Request(prompt=[3, 1, 4], max_new_tokens=2)])
     names = {c.fun_name for c in tracing.compiles(t0)}
